@@ -3,6 +3,8 @@ import sys
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("name,us_per_call,derived")
     from . import (table1_fft_variants, table2_ablation, table3_fft2d,
                    table4_plan_autotune)

@@ -41,8 +41,8 @@ BENCH_JSON = "BENCH_gemm_fft.json"
 def measured_traffic_bytes_2d(h: int, w: int, *, dtype=np.float32,
                               variant: str = "plain") -> int:
     """HBM bytes the GEMM 2-D kernel stages per image, counted from its
-    real operand buffers: the 12 four-step table arrays gemm_tables
-    actually builds, plus the split-complex in/out planes."""
+    real operand buffers: the dense DFT tables gemm_tables actually
+    builds, plus the split-complex in/out planes."""
     from repro.kernels.fft2d_gemm import gemm_tables
     tables = sum(np.asarray(t).nbytes
                  for t in gemm_tables(h, w, False, jnp.dtype(dtype), variant))

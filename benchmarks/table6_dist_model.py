@@ -114,6 +114,9 @@ def measured_rows(sizes, *, devices: int = DEVICES,
     """Run the actual pencil transforms on emulated devices (subprocess so
     this process's single-device jax stays untouched)."""
     env = dict(os.environ)
+    # CPU emulation: the child must never reach for a chip this process
+    # may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")

@@ -35,14 +35,14 @@ BENCH_JSON = "BENCH_rfft2d.json"
 def measured_traffic_bytes(h: int, w: int, *, dtype=np.float32) -> int:
     """HBM bytes the fused rfft kernel actually stages per image, counted
     from its REAL operand buffers — the table arrays the kernel builds
-    (``fourstep_tables_np``, cast to the working dtype) plus the input
-    plane and output spectrum ShapeDtypeStructs.  Deliberately independent
-    of :mod:`repro.tt.trace`'s accounting, so a model drift (forgotten
-    table, wrong spectrum width) shows up as model_vs_measured != 1."""
-    from repro.kernels.rfft2d_fused import fourstep_tables_np
+    (``row_tables_np`` + ``col_tables_np``, cast to the working dtype)
+    plus the input plane and output spectrum ShapeDtypeStructs.
+    Deliberately independent of :mod:`repro.tt.trace`'s accounting, so a
+    model drift (forgotten table, wrong spectrum width) shows up as
+    model_vs_measured != 1."""
+    from repro.kernels.rfft2d_fused import col_tables_np, row_tables_np
     tables = sum(np.asarray(t, dtype).nbytes
-                 for t in fourstep_tables_np(w, False)
-                 + fourstep_tables_np(h, False))
+                 for t in row_tables_np(w, False) + col_tables_np(h, False))
     itemsize = np.dtype(dtype).itemsize
     plane_in = h * w * itemsize                      # real input
     spec_out = 2 * h * (w // 2 + 1) * itemsize       # re+im half spectrum

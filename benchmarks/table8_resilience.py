@@ -193,6 +193,9 @@ print("TABLE8_JSON " + json.dumps(rows))
 
 def dist_fault_rows(devices: int = DEVICES) -> list:
     env = dict(os.environ)
+    # CPU emulation: the child must never reach for a chip this process
+    # may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")

@@ -80,36 +80,5 @@ def scale(a: SplitComplex, s) -> SplitComplex:
     return SplitComplex(a.re * s, a.im * s)
 
 
-def matmul(w: SplitComplex, x: SplitComplex, *, precision=None,
-           preferred_element_type=jnp.float32) -> SplitComplex:
-    """Complex matmul via four real matmuls (MXU path).
-
-    ``w @ x`` with w: (..., M, K), x: (..., K, N).  Four real matmuls keep
-    every FLOP on the MXU; a 3-matmul Karatsuba variant exists
-    (:func:`matmul3`) but the 4-matmul form has a friendlier fusion shape.
-    """
-    dot = lambda p, q: jnp.matmul(p, q, precision=precision,
-                                  preferred_element_type=preferred_element_type)
-    return SplitComplex(dot(w.re, x.re) - dot(w.im, x.im),
-                        dot(w.re, x.im) + dot(w.im, x.re))
-
-
-def matmul3(w: SplitComplex, x: SplitComplex, *, precision=None,
-            preferred_element_type=jnp.float32) -> SplitComplex:
-    """Complex matmul via three real matmuls (Karatsuba).
-
-    25% fewer MXU FLOPs than :func:`matmul`; trades them for three extra
-    elementwise adds on the VPU.  Used by the compute-bound four-step path.
-    """
-    dot = lambda p, q: jnp.matmul(p, q, precision=precision,
-                                  preferred_element_type=preferred_element_type)
-    k1 = dot(w.re, x.re + x.im)
-    k2 = dot(w.re + w.im, x.im)
-    k3 = dot(w.im - w.re, x.re)
-    # re = wr*xr - wi*xi = k1 - k2 - ... check: k1 = wr@xr + wr@xi ; k2 = wr@xi + wi@xi
-    # k1 - k2 = wr@xr - wi@xi  (re)  ;  k1 + k3 = wr@xi + wi@xr  (im)
-    return SplitComplex(k1 - k2, k1 + k3)
-
-
 def allclose(a: SplitComplex, b: SplitComplex, **kw) -> bool:
     return bool(np.allclose(a.re, b.re, **kw) and np.allclose(a.im, b.im, **kw))

@@ -53,8 +53,13 @@ def _log2(n: int) -> int:
 # Naive dense DFT (oracle + MXU leaf)
 # ---------------------------------------------------------------------------
 
+# fp32 DFT matmuls must not take the TPU's default single bf16 pass
+# (~1e-3 relative error); HIGHEST keeps them at fp32 accuracy
+DFT_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def dft_naive(x: SplitComplex, *, inverse: bool = False,
-              precision=None) -> SplitComplex:
+              precision=DFT_PRECISION) -> SplitComplex:
     """X = W_N x as a complex matmul: (..., N) @ (N, N)."""
     n = x.shape[-1]
     w = tw.dft_matrix(n, inverse=inverse, dtype=x.dtype)
@@ -338,7 +343,7 @@ def _best_split(n: int) -> int:
 
 def fft_four_step(x: SplitComplex, *, inverse: bool = False,
                   n1: Optional[int] = None, leaf: int = 256,
-                  precision=None) -> SplitComplex:
+                  precision=DFT_PRECISION) -> SplitComplex:
     """Four-step FFT: N = n1*n2; column DFTs (matmul), twiddle, row DFTs
     (matmul), transpose.  All compute is complex matmul + one pointwise
     multiply, i.e. MXU-dominated.

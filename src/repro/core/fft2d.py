@@ -7,7 +7,7 @@ Two execution paths behind the plan registry's ``backend`` switch:
   transpose between the two 1-D passes; XLA lowers it to an in-HBM relayout.
 - ``backend="pallas"`` — the GEMM-formulated fused kernel
   (:mod:`repro.kernels.fft2d_gemm`, ``algo="fused"``): both 1-D passes run
-  as four-step DFT matmuls inside one kernel, the column pass as left-side
+  as dense DFT matmuls inside one kernel, the column pass as left-side
   contractions, so the global transpose never materialises anywhere — not
   in HBM, not even in VMEM.  ``algo="fused_stockham"`` keeps the previous
   Stockham-stage fused kernel (:mod:`repro.kernels.fft2d_fused`) as the

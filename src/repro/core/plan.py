@@ -21,7 +21,7 @@ Stockham-stage kernel stays reachable as the explicit-algo oracle
 pencil-in-VMEM kernel (:mod:`repro.kernels.fft3d_fused`).
 
 GEMM-fused plans additionally carry a ``variant``: ``"plain"`` casts the
-four-step operand tables straight to the working dtype, while
+DFT operand tables straight to the working dtype, while
 ``"compensated"`` (the auto default for sub-fp32 dtypes) stores them as
 split hi/lo pairs and accumulates in fp32 — the precision-compensated
 bf16 path that halves the VMEM working set (the 1024x1024 capacity

@@ -1,10 +1,8 @@
 """Multi-device layer: pencil FFTs, compressed collectives, straggler
 mitigation, pipeline parallelism.
 
-Everything here speaks shard_map + named mesh axes and imports jax through
-:mod:`repro.dist._compat`, so one jax-version quirk never takes the whole
-distributed layer down (the failure mode that kept four test modules
-skipped before this package existed).
+Everything here speaks shard_map + named mesh axes, taken from
+:mod:`repro.dist._compat`.
 """
 from . import compression, pencil, pipeline, straggler  # noqa: F401
 from ._compat import all_to_all, make_mesh, shard_map  # noqa: F401

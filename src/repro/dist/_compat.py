@@ -1,51 +1,23 @@
-"""Version-tolerant imports for the distributed layer.
+"""The distributed layer's shard_map and mesh entry points.
 
-``shard_map`` has lived in three places across jax releases (top-level
-``jax.shard_map`` on new versions, ``jax.experimental.shard_map.shard_map``
-before that) and ``jax.sharding.AxisType`` does not exist at all on older
-builds — the exact fragility that broke the seed's mesh construction
-(fixed in :func:`repro.launch.mesh.auto_axis_types_kw`).  Every
-``repro.dist`` module and every multi-device test snippet imports through
-this shim instead of hardcoding one layout.
+Every ``repro.dist`` module and every multi-device test snippet imports
+``shard_map``, ``shard_map_unchecked`` and ``make_mesh`` from here.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.launch.mesh import auto_axis_types_kw, make_mesh  # noqa: F401  (re-export)
+from repro.launch.mesh import make_mesh  # noqa: F401  (re-export)
 
-
-def _resolve_shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    try:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: PLC0415
-        return sm
-    except ImportError:                    # very old layout: module attr
-        from jax.experimental import shard_map as _mod  # noqa: PLC0415
-        return _mod.shard_map
-
-
-shard_map = _resolve_shard_map()
+shard_map = jax.shard_map
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """shard_map with the output-replication check disabled.
-
-    pallas_call has no replication rule on several jax versions, so any
-    shard_map body that may dispatch to the Pallas kernels (the pencil
-    FFTs with ``backend="pallas"``) must opt out of the check.  The flag
-    itself was renamed across releases (``check_rep`` -> ``check_vma``);
-    try both, then fall back to a plain (checked) shard_map.
-    """
-    for kw in ("check_rep", "check_vma"):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **{kw: False})
-        except TypeError:
-            continue
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """shard_map with the varying-manual-axes check off: pallas_call has
+    no rule for it, so a body that may dispatch to the Pallas kernels (the
+    pencil FFTs with ``backend="pallas"``) must opt out."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def all_to_all(x, axis_name: str, split_axis: int, concat_axis: int):

@@ -8,9 +8,9 @@ sub-volume resident in VMEM and runs all three passes on it back to back —
 per volume exactly one HBM read and one HBM write of each split-complex
 plane, zero relayouts:
 
-- **W pass** — :func:`repro.kernels.rfft2d_fused.fft_last_fourstep` over
+- **W pass** — :func:`repro.kernels.fourstep.fft_last_fourstep` over
   the contiguous last axis (every (d, h) pencil at once).
-- **H pass** — :func:`~repro.kernels.rfft2d_fused.fft_col_fourstep` along
+- **H pass** — :func:`~repro.kernels.fourstep.fft_col_fourstep` along
   axis -2: a *left-side* DFT contraction, so the W-H transpose is absorbed
   into the matmul operand order.
 - **D pass** — the same left-side contraction with the (H, W) plane
@@ -18,12 +18,14 @@ plane, zero relayouts:
   (bb, D, H*W) makes D the contracted axis of ``fft_col_fourstep`` and the
   D-H-W relayout disappears the same way.
 
-Each pass is one level of Bailey four-step — dense DFT-matrix matmuls with
-a pointwise inter-factor twiddle, single dense DFT below the leaf — fed by
-host-built tables, exactly the GEMM formulation of the 2-D kernel
-(:mod:`repro.kernels.fft2d_gemm`), whose precision-compensated bf16
-variant (split tables + fp32 accumulation, bf16 resident tile) is also
-available here: a 128^3 fp32 brick busts 16 MiB VMEM, the bf16 one fits.
+Each pass is one level of Bailey four-step (:mod:`repro.kernels.fourstep`)
+— dense DFT-matrix matmuls with a pointwise inter-factor twiddle, single
+dense DFT below the leaf — fed by host-built tables.  The 2-D kernel's
+(:mod:`repro.kernels.fft2d_gemm`) precision-compensated bf16 variant
+(split tables + fp32 accumulation, bf16 resident tile) is also available
+here: a 128^3 fp32 brick busts 16 MiB VMEM, the bf16 one fits.  The
+four-step reshapes make Mosaic refuse this kernel for the TPU
+(``unsupported shape cast``); it runs in interpret mode only.
 """
 from __future__ import annotations
 
@@ -35,9 +37,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.complexmath import SplitComplex
 from repro.core.fft1d import _best_split
-from .rfft2d_fused import (fourstep_tables_np, fft_last_fourstep,
-                           fft_col_fourstep)
-from .fft2d_gemm import VARIANTS, split_table_np, _unsplit
+from .fourstep import fourstep_tables_np, fft_last_fourstep, fft_col_fourstep
+from .fft2d_gemm import VARIANTS, cast_tables
 
 # The fused brick runs three memory-bound passes back to back, so its
 # dense-leaf crossover sits one octave below the 2-D kernel's: at 256 a
@@ -68,9 +69,15 @@ def gemm_tables3(d: int, h: int, w: int, inverse: bool, dtype, variant: str):
     tabs = (fourstep_tables_np(w, inverse, fourstep_factors3(w))
             + fourstep_tables_np(h, inverse, fourstep_factors3(h))
             + fourstep_tables_np(d, inverse, fourstep_factors3(d)))
-    if variant == "compensated":
-        return [split_table_np(t, dtype) for t in tabs]
-    return [jnp.asarray(t, dtype) for t in tabs]
+    return cast_tables(tabs, dtype, variant)
+
+
+def _unsplit(tabs, compensated: bool):
+    """Rebuild fp32 tables from split hi/lo pairs (plain tables pass)."""
+    if compensated:
+        return tuple(t[0].astype(jnp.float32) + t[1].astype(jnp.float32)
+                     for t in tabs)
+    return tuple(tabs)
 
 
 def _fft3d_kernel(*refs, d: int, h: int, w: int, facs, inverse: bool,

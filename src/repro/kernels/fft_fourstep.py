@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.complexmath import SplitComplex
 from repro.core import twiddle as tw
+from .fft2d_gemm import mxu_dot
 
 
 def _split_n(n: int) -> tuple:
@@ -36,7 +37,7 @@ def _split_n(n: int) -> tuple:
 
 def _cmatmul(ar, ai, br, bi, *, left: bool):
     """Complex matmul via 4 real matmuls; left: W@A else A@W."""
-    dot = lambda p, q: jnp.dot(p, q, preferred_element_type=jnp.float32)
+    dot = mxu_dot
     if left:
         return (dot(br, ar) - dot(bi, ai), dot(br, ai) + dot(bi, ar))
     return (dot(ar, br) - dot(ai, bi), dot(ar, bi) + dot(ai, br))

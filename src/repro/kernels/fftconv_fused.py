@@ -64,8 +64,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.complexmath import SplitComplex
-from repro.kernels.rfft2d_fused import (fft_last_fourstep, fourstep_factors,
-                                        fourstep_tables_np)
+from repro.kernels.fourstep import (fft_last_fourstep, fourstep_factors,
+                                    fourstep_tables_np)
 
 
 def conv_tables(m: int, dtype):

@@ -111,7 +111,7 @@ def fft2d_gemm(x: SplitComplex, *, inverse: bool = False,
                block_batch: int = 1, variant: str = "plain",
                interpret: bool = None) -> SplitComplex:
     """GEMM-formulated fused 2-D FFT over the last two axes (any leading
-    batch dims): four-step DFT matmul passes, transpose absorbed; see
+    batch dims): dense DFT matmul passes, transpose absorbed; see
     :mod:`repro.kernels.fft2d_gemm`.  ``variant="compensated"`` runs the
     precision-compensated bf16 path (split tables + fp32 accumulation)."""
     if interpret is None:

@@ -1,37 +1,35 @@
 """Fused real-input 2-D FFT Pallas kernel (rfft2 / irfft2).
 
-The complex fused kernel (:mod:`repro.kernels.fft2d_fused`) already keeps
+The complex fused kernel (:mod:`repro.kernels.fft2d_gemm`) already keeps
 the §5 global transpose off HBM; this kernel additionally exploits the
-input being *real*, which halves both FLOPs and HBM plane traffic:
+input being *real*, which halves HBM plane traffic and the column pass:
 
-- **Row-pair packing** — rows ``2j`` and ``2j+1`` of the real (H, W) tile
-  become the re/im planes of ONE complex row, so the row pass runs H/2
-  complex FFTs of length W instead of H (the classic two-real-FFTs-in-one
-  trick).
-- **Hermitian untangle in-VMEM** — the packed spectra split back into the
-  two rows' half spectra ``A = (Z[k] + conj(Z[-k]))/2`` and
-  ``B = -i (Z[k] - conj(Z[-k]))/2`` for k = 0..W/2, never materialising
-  the redundant half.
-- **Half-width column pass** — the column FFT runs on the (H, W/2+1) half
-  spectrum as a *left-side* DFT contraction along the H axis, so the tile
-  transpose the row-column schedule pays for is absorbed into the matmul
-  operand order and never round-trips anywhere (not even inside VMEM).
+- **Real row pass** — the (H, W) real tile times a (W, W/2) cosine table
+  and a (W, W/2) sine table gives bins 0..W/2-1 of every row.  Bins 0 and
+  W/2 of a real row are both real, so the sine table's column 0 (all
+  zero) is replaced by the Nyquist kernel ``(-1)^j``: the Nyquist bin
+  rides in the imaginary lane of bin 0 and the half spectrum stays W/2
+  lanes wide.
+- **Half-width column pass** — ``F_H`` times the (H, W/2) packed half
+  spectrum as *left-side* matmuls, so the tile transpose of the
+  row-column schedule never materialises.  The four real products
+  ``A = Fr·Pr, B = Fi·Pi, C = Fr·Pi, D = Fi·Pr`` give bins 1..W/2-1 as
+  ``(A - B, C + D)``, and in lane 0 they hold the DC column ``(A, D)``
+  and the Nyquist column ``(C, B)`` separately: no unpacking pass.
 
-Both 1-D passes are one level of Bailey four-step — dense DFT-matrix
-matmuls (MXU work on TPU, fast GEMMs under interpret mode on CPU) with a
-pointwise inter-factor twiddle — fed by host-built tables passed as kernel
-operands: ``n = n1 * n2`` with ``n1 = 1`` (a single dense DFT) below the
-leaf size.  Per image the kernel moves one real plane in and one half
-spectrum out: ~half the complex fused kernel's HBM traffic, and the VMEM
-working set is the half-width tile (the 1024x1024 fp32 case fits the
-16 MiB v5e budget that the complex kernel busts — see
-:func:`repro.tt.trace.trace_plan`).
-
-The inverse twin repacks the half spectra (Hermitian extension of each
-row pair into one complex row), runs the inverse column and row passes,
-and writes the real plane; ``s=`` truncate/pad fits happen upstream in
-:func:`repro.core.fft2d.irfft2`, which hands this kernel an already
+The inverse twin runs the row pass first: each row's half spectrum times
+the Hermitian-weighted (W/2, W) cosine/sine tables (weights 1, 2, ..., 2)
+plus the Nyquist column times ``(-1)^j`` gives a complex (H, W) plane;
+the real part of ``F_H^{-1}`` times it is the output.  Dropping the
+imaginary parts of the DC and Nyquist bins — the C2R convention — falls
+out of taking that real part.  ``s=`` truncate/pad fits happen upstream
+in :func:`repro.core.fft2d.irfft2`, which hands this kernel an already
 fitted spectrum.
+
+Both passes are dense DFT-matrix matmuls in the compilable form of
+:mod:`repro.kernels.fft2d_gemm` (2-D matmuls on ROW_TILE slabs, fp32 at
+``Precision.HIGHEST``), fed by host-built float64 tables.  Per image the
+kernel moves one real plane in and one (H, W/2+1) half spectrum out.
 """
 from __future__ import annotations
 
@@ -39,170 +37,146 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.complexmath import SplitComplex
-from repro.core.fft1d import _best_split
-
-# below this length a single dense DFT matmul beats the four-step's extra
-# twiddle/reshape traffic (mirrors resolve_algo's naive-leaf region)
-FOURSTEP_LEAF = 256
+from .fft2d_gemm import (_check_dims, compiler_params, fit_block_batch,
+                         for_row_tiles, lane_chunks, nbytes, row_tile,
+                         slab_dot, table_dot, table_spec, vmem_need)
 
 
-def fourstep_factors(n: int):
-    """(n1, n2) with n = n1 * n2: the one-level four-step split used by the
-    kernel (and mirrored by the :mod:`repro.tt.trace` cost model).  n1 == 1
-    means a single dense DFT matmul."""
-    n1 = 1 if n <= FOURSTEP_LEAF else _best_split(n)
-    return n1, n // n1
+def _angles(n_in: int, n_out: int, n: int) -> np.ndarray:
+    """2*pi*(j*k mod n)/n on an (n_in, n_out) grid, reduced mod n first so
+    the float64 angle stays small."""
+    jk = np.outer(np.arange(n_in), np.arange(n_out)) % n
+    return 2.0 * np.pi * jk / n
 
 
-def fourstep_tables_np(n: int, inverse: bool, factors=None):
-    """Host-built float64 tables for one four-step pass of length n, cast
-    by the caller: DFT matrices for both factors plus the inter-factor
-    twiddle ``T[k1, j2] = exp(sign * 2*pi*i * k1*j2 / n)`` — composed from
-    the (lru-cached) builders in :mod:`repro.core.twiddle`.  No 1/n
-    scaling — the inverse kernels fold one 1/(H*W) at the end.  An
-    explicit ``factors`` pair overrides :func:`fourstep_factors` (the 3-D
-    kernel's leaf crossover sits one octave lower)."""
-    from repro.core.twiddle import _dft_matrix_np, _fourstep_twiddle_np
-    n1, n2 = fourstep_factors(n) if factors is None else factors
-    assert n1 * n2 == n, (n, n1, n2)
-    sign = 1.0 if inverse else -1.0
-    w1r, w1i = _dft_matrix_np(n1, sign)
-    w2r, w2i = _dft_matrix_np(n2, sign)
-    twr, twi = _fourstep_twiddle_np(n1, n2, sign)
-    return (w1r, w1i, w2r, w2i, twr, twi)
-
-
-def fft_last_fourstep(re, im, tabs, n1: int, n2: int):
-    """Length-(n1*n2) FFT of the last axis via one four-step level.
-
-    The n1-factor DFT contracts along axis -2 as a *left* multiply
-    (einsum), so no transpose is materialised for it; only the four-step
-    output reordering transposes the two small factor axes.
-    """
-    w1r, w1i, w2r, w2i, twr, twi = tabs
-    b = re.shape[:-1]
-    re = re.reshape(*b, n1, n2)
-    im = im.reshape(*b, n1, n2)
-    if n1 > 1:
-        yr = jnp.einsum("ka,...an->...kn", w1r, re) \
-            - jnp.einsum("ka,...an->...kn", w1i, im)
-        yi = jnp.einsum("ka,...an->...kn", w1i, re) \
-            + jnp.einsum("ka,...an->...kn", w1r, im)
-        re, im = yr * twr - yi * twi, yr * twi + yi * twr
-    zr = re @ w2r - im @ w2i
-    zi = re @ w2i + im @ w2r
-    # output ordering X[k2*n1 + k1] = Z[k1, k2]
-    zr = jnp.swapaxes(zr, -1, -2).reshape(*b, n1 * n2)
-    zi = jnp.swapaxes(zi, -1, -2).reshape(*b, n1 * n2)
-    return zr, zi
-
-
-def fft_col_fourstep(re, im, tabs, n1: int, n2: int):
-    """Length-(n1*n2) FFT along axis -2 of an (..., H, C) tile — the column
-    pass — as left-side DFT contractions, absorbing the tile transpose."""
-    w1r, w1i, w2r, w2i, twr, twi = tabs
-    b = re.shape[:-2]
-    c = re.shape[-1]
-    re = re.reshape(*b, n1, n2, c)
-    im = im.reshape(*b, n1, n2, c)
-    if n1 > 1:
-        yr = jnp.einsum("ka,...anc->...knc", w1r, re) \
-            - jnp.einsum("ka,...anc->...knc", w1i, im)
-        yi = jnp.einsum("ka,...anc->...knc", w1i, re) \
-            + jnp.einsum("ka,...anc->...knc", w1r, im)
-        twr = twr[..., None]
-        twi = twi[..., None]
-        re, im = yr * twr - yi * twi, yr * twi + yi * twr
-    zr = jnp.einsum("kb,...nbc->...nkc", w2r, re) \
-        - jnp.einsum("kb,...nbc->...nkc", w2i, im)
-    zi = jnp.einsum("kb,...nbc->...nkc", w2i, re) \
-        + jnp.einsum("kb,...nbc->...nkc", w2r, im)
-    zr = jnp.swapaxes(zr, -3, -2).reshape(*b, n1 * n2, c)
-    zi = jnp.swapaxes(zi, -3, -2).reshape(*b, n1 * n2, c)
-    return zr, zi
-
-
-def _conj_rev(x):
-    """x[(W-k) % W] for k = 0..W/2 on a length-W last axis (the conj(Z[-k])
-    gather of the Hermitian untangle, built from a flip — no gather op)."""
-    h = x.shape[-1] // 2
-    return jnp.concatenate([x[..., :1], jnp.flip(x[..., h:], -1)], -1)
-
-
-def _rfft2d_kernel(w1rw, w1iw, w2rw, w2iw, twrw, twiw,
-                   w1rh, w1ih, w2rh, w2ih, twrh, twih,
-                   x_ref, ore_ref, oim_ref, *,
-                   h: int, w: int, n1w: int, n2w: int, n1h: int, n2h: int):
-    """One batch tile: packed row FFT, Hermitian untangle, half-width
-    column FFT — all VMEM-resident."""
-    x = x_ref[...]                               # (bb, h, w) real
-    re = x[:, 0::2, :]                           # row pairs -> one complex
-    im = x[:, 1::2, :]                           # row: (bb, h/2, w)
-    tw_w = (w1rw[...], w1iw[...], w2rw[...], w2iw[...], twrw[...], twiw[...])
-    re, im = fft_last_fourstep(re, im, tw_w, n1w, n2w)
-    # untangle Z -> A (even rows), B (odd rows), bins k = 0..w/2
+def row_tables_np(w: int, inverse: bool):
+    """The real-axis tables.  Forward: (W, W/2) ``cos`` and ``-sin`` with
+    the Nyquist kernel ``(-1)^j`` in the sine table's column 0.  Inverse:
+    (W/2, W) Hermitian-weighted ``c_k cos`` and ``c_k sin`` (c_0 = 1,
+    c_k = 2 otherwise; the Nyquist bin enters separately)."""
     hw = w // 2
-    cr, ci = _conj_rev(re), _conj_rev(im)
-    rk, ik = re[..., :hw + 1], im[..., :hw + 1]
-    ar, ai = (rk + cr) * 0.5, (ik - ci) * 0.5
-    br, bi = (ik + ci) * 0.5, (cr - rk) * 0.5
-    bb = x.shape[0]
-    re2 = jnp.stack([ar, br], 2).reshape(bb, h, hw + 1)
-    im2 = jnp.stack([ai, bi], 2).reshape(bb, h, hw + 1)
-    # column FFT on the half-width tile (transpose absorbed into the
-    # left-side contraction)
-    tw_h = (w1rh[...], w1ih[...], w2rh[...], w2ih[...], twrh[...], twih[...])
-    re2, im2 = fft_col_fourstep(re2, im2, tw_h, n1h, n2h)
-    ore_ref[...] = re2
-    oim_ref[...] = im2
+    if inverse:
+        ang = _angles(hw, w, w)
+        c = np.full((hw, 1), 2.0)
+        c[0] = 1.0
+        return c * np.cos(ang), c * np.sin(ang)
+    ang = _angles(w, hw, w)
+    cr, ci = np.cos(ang), -np.sin(ang)
+    ci[:, 0] = 1.0 - 2.0 * (np.arange(w) % 2)
+    return cr, ci
 
 
-def _irfft2d_kernel(w1rw, w1iw, w2rw, w2iw, twrw, twiw,
-                    w1rh, w1ih, w2rh, w2ih, twrh, twih,
-                    xre_ref, xim_ref, o_ref, *,
-                    h: int, w: int, n1w: int, n2w: int, n1h: int, n2h: int):
-    """Inverse twin: inverse column FFT, row-pair repack (Hermitian
-    extension), inverse row FFT, write the real plane."""
-    re = xre_ref[...]                            # (bb, h, w/2+1)
-    im = xim_ref[...]
-    tw_h = (w1rh[...], w1ih[...], w2rh[...], w2ih[...], twrh[...], twih[...])
-    re, im = fft_col_fourstep(re, im, tw_h, n1h, n2h)
-    # repack: rows 2j/2j+1's half spectra A/B -> Z = A_ext + i * B_ext.
-    # The C2R convention (numpy, and the jnp path's trailing .re) ignores
-    # the imaginary parts of the DC and Nyquist bins; here they MUST be
-    # zeroed explicitly — a complex Nyquist (e.g. after an s= width
-    # truncation) would otherwise leak row 2j+1's residue into row 2j.
-    hw = w // 2
-    ar, ai = re[:, 0::2, :], im[:, 0::2, :]      # (bb, h/2, w/2+1)
-    br, bi = re[:, 1::2, :], im[:, 1::2, :]
-    z0 = jnp.zeros_like(ai[..., :1])
-    drop_ends = lambda q: jnp.concatenate([z0, q[..., 1:hw], z0], -1)
-    ai, bi = drop_ends(ai), drop_ends(bi)
-    ext = lambda q, s: jnp.concatenate(
-        [q, s * jnp.flip(q[..., 1:hw], -1)], -1)  # Hermitian-extend to w
-    zr = ext(ar, 1.0) - ext(bi, -1.0)
-    zi = ext(ai, -1.0) + ext(br, 1.0)
-    tw_w = (w1rw[...], w1iw[...], w2rw[...], w2iw[...], twrw[...], twiw[...])
-    zr, zi = fft_last_fourstep(zr, zi, tw_w, n1w, n2w)
-    bb = re.shape[0]
-    out = jnp.stack([zr, zi], 2).reshape(bb, h, w)   # re -> 2j, im -> 2j+1
-    o_ref[...] = out * jnp.asarray(1.0 / (h * w), out.dtype)
+def col_tables_np(h: int, inverse: bool):
+    """Dense ``F_H`` (re, im), the sign set by the direction."""
+    ang = _angles(h, h, h)
+    return np.cos(ang), (1.0 if inverse else -1.0) * np.sin(ang)
 
 
 def _tables(h: int, w: int, inverse: bool, dtype):
-    tabs_w = fourstep_tables_np(w, inverse)
-    tabs_h = fourstep_tables_np(h, inverse)
-    return [jnp.asarray(t, dtype) for t in tabs_w + tabs_h]
+    return [jnp.asarray(t, dtype)
+            for t in row_tables_np(w, inverse) + col_tables_np(h, inverse)]
 
 
-def _check_dims(h: int, w: int):
-    for d in (h, w):
-        if d & (d - 1) or d < 2:
-            raise ValueError("the fused rfft kernel needs power-of-two "
-                             f"tile dims >= 2, got {(h, w)}")
+def _rfft2d_kernel(cr, ci, fhr, fhi, x_ref, ore_ref, oim_ref, pre, pim, *,
+                   h: int, w: int, bb: int):
+    """One batch tile: packed real row pass into the scratch planes, then
+    the half-width column pass with DC/Nyquist split out of lane 0."""
+    hw = w // 2
+    dt = ore_ref.dtype
+
+    def image(b, carry):
+        def row_pass(rows):
+            a = x_ref[b, rows, :]
+            for cols in lane_chunks(hw):
+                pre[rows, cols] = table_dot(a, cr, cols).astype(pre.dtype)
+                pim[rows, cols] = table_dot(a, ci, cols).astype(pim.dtype)
+
+        def col_pass(rows):
+            for cols in lane_chunks(hw):
+                p, q = pre[:, cols], pim[:, cols]
+                a_, b_ = slab_dot(fhr, rows, p), slab_dot(fhi, rows, q)
+                c_, d_ = slab_dot(fhr, rows, q), slab_dot(fhi, rows, p)
+                yr, yi = a_ - b_, c_ + d_
+                if cols.start == 0:
+                    # lane 0 holds DC (A, D) and Nyquist (C, B) apart
+                    lane = jax.lax.broadcasted_iota(jnp.int32, yr.shape, 1)
+                    yr = jnp.where(lane == 0, a_, yr)
+                    yi = jnp.where(lane == 0, d_, yi)
+                    ore_ref[b, rows, pl.ds(hw, 1)] = c_[:, :1].astype(dt)
+                    oim_ref[b, rows, pl.ds(hw, 1)] = b_[:, :1].astype(dt)
+                ore_ref[b, rows, cols] = yr.astype(dt)
+                oim_ref[b, rows, cols] = yi.astype(dt)
+
+        for_row_tiles(h, row_pass)
+        for_row_tiles(h, col_pass)
+        return carry
+
+    jax.lax.fori_loop(0, bb, image, 0)
+
+
+def _irfft2d_kernel(er, ei, ghr, ghi, xre_ref, xim_ref, o_ref, ure, uim, *,
+                    h: int, w: int, bb: int):
+    """Inverse twin: Hermitian-weighted row pass (complex (H, W) plane into
+    the scratch), then the real part of the inverse column pass."""
+    hw = w // 2
+    dt = o_ref.dtype
+    scale = 1.0 / (h * w)
+
+    def image(b, carry):
+        def row_pass(rows):
+            a = xre_ref[b, rows, pl.ds(0, hw)]
+            c = xim_ref[b, rows, pl.ds(0, hw)]
+            nr = xre_ref[b, rows, pl.ds(hw, 1)].astype(jnp.float32)
+            ni = xim_ref[b, rows, pl.ds(hw, 1)].astype(jnp.float32)
+            for cols in lane_chunks(w):
+                lane = jax.lax.broadcasted_iota(
+                    jnp.int32, (a.shape[0], cols.size), 1)
+                alt = jnp.where(lane % 2 == 0, 1.0, -1.0)  # Nyquist kernel
+                ure[rows, cols] = (table_dot(a, er, cols)
+                                   - table_dot(c, ei, cols)
+                                   + nr * alt).astype(ure.dtype)
+                uim[rows, cols] = (table_dot(a, ei, cols)
+                                   + table_dot(c, er, cols)
+                                   + ni * alt).astype(uim.dtype)
+
+        def col_pass(rows):
+            for cols in lane_chunks(w):
+                y = slab_dot(ghr, rows, ure[:, cols]) \
+                    - slab_dot(ghi, rows, uim[:, cols])
+                o_ref[b, rows, cols] = (y * scale).astype(dt)
+
+        for_row_tiles(h, row_pass)
+        for_row_tiles(h, col_pass)
+        return carry
+
+    jax.lax.fori_loop(0, bb, image, 0)
+
+
+def _call(kernel, ops, ins, outs, scratch, *, h, w, block_batch,
+          interpret):
+    """pallas_call plumbing shared by both directions: whole tables
+    single-buffered, blocks of images tiled over the batch grid."""
+    batch = ins[0].shape[0]
+    per_image = nbytes(*[jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                         for a in ins + outs])
+    need = lambda bb: vmem_need(blocks=bb * per_image, tables=nbytes(*ops),
+                                scratch=nbytes(*scratch),
+                                slab=row_tile(h) * max(h, w) * 4)
+    bb = fit_block_batch(min(block_batch, batch), batch, need)
+    spec = lambda a: pl.BlockSpec((bb,) + a.shape[1:], lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(kernel, h=h, w=w, bb=bb),
+        grid=(batch // bb,),
+        in_specs=[table_spec(t) for t in ops] + [spec(a) for a in ins],
+        out_specs=[spec(o) for o in outs], out_shape=outs,
+        scratch_shapes=[pltpu.VMEM(s.shape, s.dtype) for s in scratch],
+        compiler_params=compiler_params(need(bb)),
+        interpret=interpret)(*ops, *ins)
 
 
 def rfft2d_fused_pallas(x: jnp.ndarray, *, block_batch: int = 1,
@@ -211,23 +185,11 @@ def rfft2d_fused_pallas(x: jnp.ndarray, *, block_batch: int = 1,
     half spectra."""
     batch, h, w = x.shape
     _check_dims(h, w)
-    bb = min(block_batch, batch)
-    assert batch % bb == 0, (batch, bb)
-    ops = _tables(h, w, False, x.dtype)
-    n1w, n2w = fourstep_factors(w)
-    n1h, n2h = fourstep_factors(h)
-    kernel = functools.partial(_rfft2d_kernel, h=h, w=w, n1w=n1w, n2w=n2w,
-                               n1h=n1h, n2h=n2h)
-    grid = (batch // bb,)
-    in_spec = pl.BlockSpec((bb, h, w), lambda i: (i, 0, 0))
-    out_spec = pl.BlockSpec((bb, h, w // 2 + 1), lambda i: (i, 0, 0))
-    tspecs = [pl.BlockSpec(t.shape, lambda i, nd=t.ndim: (0,) * nd)
-              for t in ops]
-    out_shape = [jax.ShapeDtypeStruct((batch, h, w // 2 + 1), x.dtype)] * 2
-    ore, oim = pl.pallas_call(
-        kernel, grid=grid, in_specs=tspecs + [in_spec],
-        out_specs=[out_spec, out_spec], out_shape=out_shape,
-        interpret=interpret)(*ops, x)
+    half = jax.ShapeDtypeStruct((h, w // 2), x.dtype)
+    spec = jax.ShapeDtypeStruct((batch, h, w // 2 + 1), x.dtype)
+    ore, oim = _call(_rfft2d_kernel, _tables(h, w, False, x.dtype), [x],
+                     [spec, spec], [half, half], h=h, w=w,
+                     block_batch=block_batch, interpret=interpret)
     return SplitComplex(ore, oim)
 
 
@@ -238,21 +200,10 @@ def irfft2d_fused_pallas(xf: SplitComplex, *, block_batch: int = 1,
     batch, h, bins = xf.re.shape
     w = 2 * (bins - 1)
     _check_dims(h, w)
-    bb = min(block_batch, batch)
-    assert batch % bb == 0, (batch, bb)
-    ops = _tables(h, w, True, xf.dtype)
-    n1w, n2w = fourstep_factors(w)
-    n1h, n2h = fourstep_factors(h)
-    kernel = functools.partial(_irfft2d_kernel, h=h, w=w, n1w=n1w, n2w=n2w,
-                               n1h=n1h, n2h=n2h)
-    grid = (batch // bb,)
-    in_spec = pl.BlockSpec((bb, h, bins), lambda i: (i, 0, 0))
-    out_spec = pl.BlockSpec((bb, h, w), lambda i: (i, 0, 0))
-    tspecs = [pl.BlockSpec(t.shape, lambda i, nd=t.ndim: (0,) * nd)
-              for t in ops]
-    out = pl.pallas_call(
-        kernel, grid=grid, in_specs=tspecs + [in_spec, in_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, h, w), xf.dtype),
-        interpret=interpret)(*ops, xf.re, xf.im)
+    plane = jax.ShapeDtypeStruct((h, w), xf.dtype)
+    (out,) = _call(_irfft2d_kernel, _tables(h, w, True, xf.dtype),
+                   [xf.re, xf.im],
+                   [jax.ShapeDtypeStruct((batch, h, w), xf.dtype)],
+                   [plane, plane], h=h, w=w, block_batch=block_batch,
+                   interpret=interpret)
     return out

@@ -10,27 +10,21 @@ from __future__ import annotations
 import jax
 
 
-def auto_axis_types_kw(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,) * n}`` on jax versions that have
-    ``jax.sharding.AxisType``, ``{}`` on older ones (where Auto is already
-    the only behaviour) — keeps mesh construction version-tolerant."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh with Auto axis types (shard_map-compatible)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **auto_axis_types_kw(len(axes)))
+                         axis_types=_auto(len(axes)))
 
 
 def data_axes(mesh) -> tuple:

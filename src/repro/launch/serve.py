@@ -25,13 +25,19 @@ def _parse_buckets(spec: str):
     return shapes
 
 
-def _spectral_main(args) -> None:
-    from repro.serve.spectral import (BucketConfig, MixItem, SpectralServer,
-                                      closed_loop, open_loop)
+def spectral_buckets(spec: str):
+    """The serving buckets for a ``--buckets`` spec: c2c + rfft per 2-D
+    shape, c2c per 1-D one (fp32, ``backend="pallas"``)."""
+    from repro.serve.spectral import BucketConfig
+    return [BucketConfig(s, kind=k) for s in _parse_buckets(spec)
+            for k in ("c2c", "rfft") if len(s) == 2 or k == "c2c"]
 
-    shapes = _parse_buckets(args.buckets)
-    buckets = [BucketConfig(s, kind=k) for s in shapes
-               for k in ("c2c", "rfft") if len(s) == 2 or k == "c2c"]
+
+def _spectral_main(args) -> None:
+    from repro.serve.spectral import (MixItem, SpectralServer, closed_loop,
+                                      open_loop)
+
+    buckets = spectral_buckets(args.buckets)
     mix = [MixItem(b.shape, b.kind, inverse=b.inverse) for b in buckets]
     with SpectralServer(buckets, unmatched=args.unmatched) as srv:
         rep = srv.prewarm_report
@@ -77,6 +83,8 @@ def main():
                     help="spectral: open-loop duration (seconds)")
     args = ap.parse_args()
 
+    from . import compile_cache
+    compile_cache.enable()
     if args.workload == "spectral":
         _spectral_main(args)
         return

@@ -38,6 +38,8 @@ def main():
     args = ap.parse_args()
 
     import jax
+    from . import compile_cache
+    compile_cache.enable()
     import repro.configs as C
     from repro.data.pipeline import DataConfig, SyntheticLM
     from repro.models import model as M, actsharding

@@ -472,14 +472,14 @@ def _fused2d_stage(a: Arch, *, h: int, w: int, batch: int, radix: int,
 
 def fourstep_table_bytes(n: int, *, elem_bytes: int = 8,
                          factors=None) -> int:
-    """Bytes of the one-level four-step operand tables the fused rfft
-    kernel stages per axis: both factor DFT matrices plus the (n1, n2)
+    """Bytes of the one-level four-step operand tables a four-step pass
+    stages per axis: both factor DFT matrices plus the (n1, n2)
     inter-factor twiddle, re+im planes (``elem_bytes`` per split-complex
-    element, matching :func:`repro.kernels.rfft2d_fused.fourstep_tables_np`).
+    element, matching :func:`repro.kernels.fourstep.fourstep_tables_np`).
     ``factors`` overrides the 2-D kernel's split rule (the 3-D kernel's
     leaf crossover sits one octave lower)."""
     if factors is None:
-        from repro.kernels.rfft2d_fused import fourstep_factors
+        from repro.kernels.fourstep import fourstep_factors
         factors = fourstep_factors(n)
     n1, n2 = factors
     return (n1 * n1 + n2 * n2 + n1 * n2) * elem_bytes
@@ -491,7 +491,7 @@ def _fourstep_pass_flops(n: int, rows: float, factors=None) -> float:
     pointwise inter-factor twiddle — the 8*n*(n1+n2) + 6*n accounting of
     :func:`_fft_pass_stage`'s four_step arm."""
     if factors is None:
-        from repro.kernels.rfft2d_fused import fourstep_factors
+        from repro.kernels.fourstep import fourstep_factors
         factors = fourstep_factors(n)
     n1, n2 = factors
     return rows * (8.0 * n * (n1 + n2) + 6.0 * n)
@@ -507,7 +507,10 @@ def _gemm2d_stage(a: Arch, *, h: int, w: int, batch: int, block_batch: int,
     pairs) and the table-side flops (two-operand reconstruction + fp32
     accumulation) but keeps the *resident tile* at the storage dtype —
     which is why the bf16 1024x1024 working set fits the 16 MiB budget
-    the fp32 one busts."""
+    the fp32 one busts.  The stage prices the one-level four-step
+    formulation (the Wormhole-style design); the TPU kernel runs dense
+    DFT passes, whose VMEM the compiler checks
+    (``tests/test_tpu_compile.py``)."""
     plane = float(h) * w * elem_bytes              # one split-complex image
     total = batch * plane
     bb = max(1, min(block_batch, batch))
@@ -574,9 +577,11 @@ def _rfft_fused2d_stage(a: Arch, *, h: int, w: int, batch: int,
     VMEM working set (which is what lets the 1024x1024 fp32 case fit the
     16 MiB budget the complex kernel busts).  Both passes are four-step
     DFT matmuls; FLOPs follow the same 8*n*(n1+n2) accounting as
-    :func:`_fft_pass_stage`'s four_step arm.
+    :func:`_fft_pass_stage`'s four_step arm.  Like :func:`_gemm2d_stage`
+    it prices the four-step formulation, not the TPU kernel's dense
+    passes.
     """
-    from repro.kernels.rfft2d_fused import fourstep_factors
+    from repro.kernels.fourstep import fourstep_factors
     wh = w // 2 + 1
     half = elem_bytes // 2
     real_plane = float(batch) * h * w * half        # the real input/output

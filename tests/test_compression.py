@@ -7,8 +7,8 @@ import numpy as np
 from _subproc import run_with_devices
 from repro.dist.compression import wire_bytes
 
-# shard_map comes from repro.dist._compat (three homes across jax versions)
-# and the mesh from repro.launch.mesh.make_mesh (axis_types-tolerant).
+# shard_map comes from repro.dist._compat and the mesh from
+# repro.launch.mesh.make_mesh (Auto axis types).
 CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P

@@ -2,8 +2,7 @@
 from _subproc import run_with_devices
 
 # Mesh construction goes through repro.launch.mesh.make_mesh and shard_map
-# through repro.dist._compat — never raw jax.make_mesh(axis_types=...) /
-# jax.shard_map, which only exist on some jax versions.
+# through repro.dist._compat, the repo's single entry points for both.
 CODE = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P

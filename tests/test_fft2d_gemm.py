@@ -103,10 +103,24 @@ def test_split_table_reconstruction_accuracy():
 def test_gemm_tables_operand_count_and_shapes():
     plain = gemm_tables(64, 128, False, jnp.float32, "plain")
     comp = gemm_tables(64, 128, False, jnp.bfloat16, "compensated")
-    assert len(plain) == len(comp) == 12
+    assert len(plain) == len(comp) == 4        # F_W (re, im), F_H (re, im)
+    assert [p.shape for p in plain] == [(128, 128)] * 2 + [(64, 64)] * 2
     for p, c in zip(plain, comp):
         assert c.shape == (2,) + p.shape       # stacked (hi, lo)
         assert c.dtype == jnp.bfloat16
+
+
+def test_block_batch_shrinks_to_vmem_budget():
+    """A block of images larger than VMEM holds shrinks to the largest
+    batch divisor that fits; small images keep the requested block."""
+    from repro.kernels.fft2d_gemm import VMEM_CAP, fit_block_batch
+    img = 8 << 20                          # one fp32 1024^2 split-complex
+    need = lambda bb: 2 * 2 * bb * img + (40 << 20)
+    assert need(2) > VMEM_CAP >= need(1)
+    assert fit_block_batch(2, 8, need) == 1
+    assert fit_block_batch(8, 8, lambda bb: bb) == 8
+    assert fit_block_batch(4, 6, lambda bb: bb) == 3   # divisor of batch
+    assert fit_block_batch(4, 8, lambda bb: VMEM_CAP + 1) == 1
 
 
 @pytest.mark.parametrize("hw", [(256, 256), (512, 512)])
