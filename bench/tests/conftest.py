@@ -1,0 +1,12 @@
+"""The benchmark's own tests run on the CPU; they put the program and the
+benchmark on the path."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
