@@ -235,5 +235,6 @@ def fft2d_gemm_pallas(x: SplitComplex, *, inverse: bool = False,
         out_specs=[data_spec, data_spec], out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((h, w), x.dtype)] * 2,
         compiler_params=compiler_params(need(bb)),
+        name="fft2d_gemm_inv" if inverse else "fft2d_gemm_fwd",
         interpret=interpret)(*ops, x.re, x.im)
     return SplitComplex(ore, oim)

@@ -23,6 +23,13 @@ recompiles on the hot path (occupancy is visible in the
 bucket to its jnp twin plan (once) and retries, mirroring the pre-warm
 degrade semantics; the requests still complete.
 
+Each stage's work runs inside a :meth:`~.metrics.Metrics.span`
+(``serve.assemble``, ``serve.h2d``, ``serve.dispatch``,
+``serve.device_wait``, ``serve.copy_back``): a histogram per stage, and a
+host span in a profiler trace.  A request's ``wait`` runs from its
+admission to the start of its batch's ``assemble``: its time in the
+scheduler.
+
 ``threaded=False`` runs the identical stage functions inline through
 :meth:`PipelinedExecutor.step` — fully deterministic for the scheduler
 edge-case tests (injectable clocks, fault sites) with zero thread
@@ -150,35 +157,38 @@ class PipelinedExecutor:
 
     def _assemble(self, bucket: BucketConfig,
                   reqs: List[Request]) -> Assembled:
-        state = self.states[bucket.label]
+        lbl, n = bucket.label, len(reqs)
+        state = self.states[lbl]
         B = state.cfg.max_batch
         dt = np.dtype(bucket.dtype)
         shape = bucket.shape if not (bucket.kind == "rfft" and bucket.inverse)\
             else bucket.shape[:-1] + (bucket.shape[-1] // 2 + 1,)
         nplanes = 2 if _input_is_complex(bucket) else 1
-        planes = [np.zeros((B,) + shape, dt) for _ in range(nplanes)]
-        for i, req in enumerate(reqs):
-            src = _payload_planes(req)
-            if len(src) < nplanes:            # real payload into a c2c slot
-                src = src + [np.zeros_like(src[0])]
-            for plane, s in zip(planes, src):
-                # pad-to-bucket: a padded-up request lands in the leading
-                # corner, zeros elsewhere (spectral interpolation)
-                region = tuple(slice(0, d) for d in s.shape)
-                plane[(i,) + region] = s.astype(dt, copy=False)
-        if nplanes == 2:
-            x = SplitComplex(jax.device_put(planes[0]),
-                             jax.device_put(planes[1]))
-        else:
-            x = jax.device_put(planes[0])
-        occupancy = len(reqs) / B
-        self.metrics.inc(bucket.label, "batches")
-        self.metrics.inc(bucket.label, "batch_items", len(reqs))
-        self.metrics.inc(bucket.label, "batch_pad_slots", B - len(reqs))
-        self.metrics.sample(bucket.label, "batch_occupancy", occupancy)
+        with self.metrics.span(lbl, "assemble", n) as t_picked:
+            planes = [np.zeros((B,) + shape, dt) for _ in range(nplanes)]
+            for i, req in enumerate(reqs):
+                src = _payload_planes(req)
+                if len(src) < nplanes:        # real payload into a c2c slot
+                    src = src + [np.zeros_like(src[0])]
+                for plane, s in zip(planes, src):
+                    # pad-to-bucket: a padded-up request lands in the leading
+                    # corner, zeros elsewhere (spectral interpolation)
+                    region = tuple(slice(0, d) for d in s.shape)
+                    plane[(i,) + region] = s.astype(dt, copy=False)
+        with self.metrics.span(lbl, "h2d", n):
+            if nplanes == 2:
+                x = SplitComplex(jax.device_put(planes[0]),
+                                 jax.device_put(planes[1]))
+            else:
+                x = jax.device_put(planes[0])
         now = self._clock()
         for req in reqs:
-            self.metrics.observe(bucket.label, "queue", now - req.t_submit)
+            self.metrics.observe(lbl, "wait", t_picked - req.t_submit)
+            self.metrics.observe(lbl, "queue", now - req.t_submit)
+        self.metrics.inc(lbl, "batches")
+        self.metrics.inc(lbl, "batch_items", n)
+        self.metrics.inc(lbl, "batch_pad_slots", B - n)
+        self.metrics.sample(lbl, "batch_occupancy", n / B)
         return Assembled(state=state, requests=reqs, x=x, t_staged=now)
 
     def _call_with_degrade(self, state: BucketState, x):
@@ -204,20 +214,24 @@ class PipelinedExecutor:
             return state.fn(x)
 
     def _dispatch(self, asm: Assembled):
-        _faults.check("serve.step", tag=asm.state.label)
-        return self._call_with_degrade(asm.state, asm.x)
+        with self.metrics.span(asm.state.label, "dispatch",
+                               len(asm.requests)):
+            _faults.check("serve.step", tag=asm.state.label)
+            return self._call_with_degrade(asm.state, asm.x)
 
     def _drain(self, asm: Assembled, y) -> None:
-        jax.block_until_ready(y)
-        if isinstance(y, SplitComplex):
-            planes = [np.asarray(y.re), np.asarray(y.im)]
-            results = [SplitComplex(planes[0][i], planes[1][i])
-                       for i in range(len(asm.requests))]
-        else:
-            host = np.asarray(y)
-            results = [host[i] for i in range(len(asm.requests))]
+        lbl, n = asm.state.label, len(asm.requests)
+        with self.metrics.span(lbl, "device_wait", n):
+            jax.block_until_ready(y)
+        with self.metrics.span(lbl, "copy_back", n):
+            if isinstance(y, SplitComplex):
+                planes = [np.asarray(y.re), np.asarray(y.im)]
+                results = [SplitComplex(planes[0][i], planes[1][i])
+                           for i in range(n)]
+            else:
+                host = np.asarray(y)
+                results = [host[i] for i in range(n)]
         now = self._clock()
-        lbl = asm.state.label
         fallback = asm.state.plan.backend != asm.state.requested_backend
         for req, val in zip(asm.requests, results):
             self.metrics.observe(lbl, "service", now - asm.t_staged)

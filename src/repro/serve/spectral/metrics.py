@@ -1,12 +1,29 @@
-"""Per-bucket serving metrics: counters, latency histograms, gauges, and a
-JSON snapshot endpoint.
+"""Per-bucket serving metrics: counters, latency histograms, gauges, spans
+and a JSON snapshot endpoint.
 
 Latencies land in fixed log-spaced histograms (10 buckets per decade from
 10us to 2min) so p50/p99 come from bucket edges without storing samples —
-bounded memory at any request rate.  Three histograms per bucket: ``queue``
-(admission → dispatch), ``service`` (dispatch → results on host) and
-``e2e`` (admission → terminal).  Gauges (queue depth at admission, batch
-occupancy at dispatch) keep count/sum/max running stats.
+bounded memory at any request rate.  Per bucket, every histogram holds one
+observation per request (a batch's span counts once for each request it
+carries), so the means add up:
+
+- ``queue``: admission → batch staged on the device.  It is ``wait``
+  (admission → the staging stage starts on its batch: the scheduler's
+  share) + ``assemble`` (numpy planes allocated and payloads copied in) +
+  ``h2d`` (the ``jax.device_put`` calls as the staging thread sees them),
+  up to the spans' own microseconds;
+- ``service``: staged → results on the host; it holds ``dispatch`` (the
+  fault-site check and the jitted call), ``device_wait``
+  (``block_until_ready``), ``copy_back`` (device planes to numpy, sliced
+  per request) and the hand-offs between the pipeline's threads;
+- ``e2e``: admission → terminal.
+
+:meth:`Metrics.span` times a stage into its histogram and opens a
+``jax.profiler.TraceAnnotation`` named ``serve.<stage>``, so the same code
+shows as a host span beside the device ops of a profiler trace.  With no
+profiler running the annotation costs about a microsecond.  Gauges (queue
+depth at admission, batch occupancy at dispatch) keep count/sum/max
+running stats.
 
 The snapshot is a plain JSON-able dict; :func:`start_http` serves it at
 ``GET /metrics`` from a daemon thread (port 0 = ephemeral) so a load
@@ -17,12 +34,17 @@ bucket's attempt/failure/fallback counters into its snapshot section.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import threading
-from typing import Dict, Optional
+import time
+from typing import Callable, Dict, Optional
 
-HIST_NAMES = ("queue", "service", "e2e")
+from jax.profiler import TraceAnnotation
+
+HIST_NAMES = ("queue", "service", "e2e", "wait", "assemble", "h2d",
+              "dispatch", "device_wait", "copy_back")
 
 COUNTERS = ("admitted", "rejected_nobucket", "rejected_backpressure",
             "padded_up", "completed", "timed_out_queued",
@@ -43,7 +65,8 @@ class LatencyHistogram:
         self.sum_s = 0.0
         self.max_s = 0.0
 
-    def record(self, seconds: float) -> None:
+    def record(self, seconds: float, n: int = 1) -> None:
+        """Add ``n`` observations of ``seconds`` each."""
         s = max(0.0, float(seconds))
         lo = 0
         hi = len(self.edges)
@@ -53,9 +76,9 @@ class LatencyHistogram:
                 hi = mid
             else:
                 lo = mid + 1
-        self.counts[lo] += 1
-        self.total += 1
-        self.sum_s += s
+        self.counts[lo] += n
+        self.total += n
+        self.sum_s += n * s
         self.max_s = max(self.max_s, s)
 
     def percentile(self, p: float) -> float:
@@ -99,9 +122,11 @@ class _Gauge:
 
 
 class Metrics:
-    """Thread-safe per-bucket counters + histograms + gauges."""
+    """Thread-safe per-bucket counters + histograms + gauges.  ``clock``
+    times :meth:`span`; the server passes its own."""
 
-    def __init__(self):
+    def __init__(self, clock: Callable[[], float] = time.monotonic):
+        self._clock = clock
         self._lock = threading.Lock()
         self._counters: Dict[str, Dict[str, int]] = {}
         self._hists: Dict[str, Dict[str, LatencyHistogram]] = {}
@@ -121,10 +146,23 @@ class Metrics:
             self._counters[label][name] = \
                 self._counters[label].get(name, 0) + n
 
-    def observe(self, label: str, hist: str, seconds: float) -> None:
+    def observe(self, label: str, hist: str, seconds: float,
+                n: int = 1) -> None:
         with self._lock:
             self._bucket(label)
-            self._hists[label][hist].record(seconds)
+            self._hists[label][hist].record(seconds, n)
+
+    @contextlib.contextmanager
+    def span(self, label: str, name: str, n: int = 1):
+        """Time the enclosed stage into histogram ``name`` with weight
+        ``n`` (the requests of the batch), inside a profiler annotation
+        ``serve.<name>``; yields the stage's start on the clock.  A stage
+        that raises records nothing."""
+        with TraceAnnotation(f"serve.{name}"):
+            t0 = self._clock()
+            yield t0
+            dt = self._clock() - t0
+        self.observe(label, name, dt, n)
 
     def sample(self, label: str, gauge: str, value: float) -> None:
         with self._lock:

@@ -61,7 +61,7 @@ class SpectralServer:
                  depth: int = 2, threaded: bool = True, prewarm: bool = True,
                  tune: bool = False, tune_batch: Optional[int] = None,
                  clock: Callable[[], float] = time.monotonic):
-        self.metrics = Metrics()
+        self.metrics = Metrics(clock=clock)
         self._clock = clock
         self._lock = threading.Lock()
         self._records: Dict[object, RequestRecord] = {}

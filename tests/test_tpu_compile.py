@@ -2,12 +2,15 @@
 
 Each test compiles one kernel (``interpret=False``) at serving size for a
 described, unattached v5e chip and asserts that the compiled program
-holds the Mosaic kernel (``tpu_custom_call``).  Nothing runs; the TPU
-compiler's verdict — scoped VMEM, shape casts, gathers — is what these
-guard.  The topology is described inside a fixture, never at import, so
-every test worker collects the same tests; where it cannot be described
-the tests skip from that fixture.
+holds the Mosaic kernel (``tpu_custom_call``), under the name a profiler
+trace shows.  Nothing runs; the TPU compiler's verdict — scoped VMEM,
+shape casts, gathers — is what these guard.  The topology is described
+inside a fixture, never at import, so every test worker collects the
+same tests; where it cannot be described the tests skip from that
+fixture.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -65,6 +68,11 @@ def test_fft2d_gemm_compiles(one_chip, n, inverse, dtype, variant):
         lambda x: ops.fft2d_gemm(x, inverse=inverse, variant=variant,
                                  interpret=False), x)
     assert "tpu_custom_call" in txt
+    # the trace tells the directions apart by the kernel's instruction name
+    name, other = ("fft2d_gemm_inv", "fft2d_gemm_fwd") if inverse else \
+        ("fft2d_gemm_fwd", "fft2d_gemm_inv")
+    assert re.search(rf"%{name}\.\d+ = .* custom-call\(", txt)
+    assert other not in txt
 
 
 @pytest.mark.parametrize("n", [256, 1024])
