@@ -86,8 +86,7 @@ def serve_phase(buckets: str = SERVE_BUCKETS, *, requests: int = 3,
             check(plan.backend == "pallas" and plan.algo == "fused",
                   f"{lbl}: plan is {plan.backend}/{plan.algo}, "
                   "not pallas/fused")
-            hlo = st.fn.lower(zeros_input(st.cfg, st.cfg.max_batch)) \
-                .compile().as_text()
+            hlo = st.fn.lower(zeros_input(st)).compile().as_text()
             kernel = "tpu_custom_call" in hlo
             check(kernel or not on_tpu,
                   f"{lbl}: no Pallas kernel in the compiled program")

@@ -4,31 +4,42 @@ Three stages, double-buffered through bounded queues, mirroring the
 paper's decoupling of data movement from compute on the Tensix — device
 dispatch never waits on host-side batch assembly:
 
-1. **Staging** (host): pull a batch from the scheduler, stack/pad payloads
-   into the bucket's fixed ``(max_batch, *shape)`` geometry, and
-   ``device_put`` the planes.  Runs on the :class:`repro.data.Prefetcher`
-   thread — the same bounded prefetch primitive the training data pipeline
-   uses — with ``depth`` in-flight batches (2 = double buffering), so
-   backpressure propagates from the device up to admission.
+1. **Staging** (host): pull a batch from the scheduler and ``device_put``
+   each live request's own payload planes, one transfer per plane per
+   request.  No host array of the batch geometry is built: empty slots
+   (and the imaginary plane of a real payload in a c2c bucket) take the
+   bucket's zero slot, which lives on the device.  Runs on the
+   :class:`repro.data.Prefetcher` thread — the same bounded prefetch
+   primitive the training data pipeline uses — with ``depth`` in-flight
+   batches (2 = double buffering), so backpressure propagates from the
+   device up to admission.
 2. **Dispatch**: consult the ``serve.step`` fault site, then call the
-   bucket's jitted plan.  JAX dispatch is async, so this thread hands the
-   in-flight computation straight to the drain queue.
-3. **Drain** (host): ``block_until_ready``, pull results back as numpy,
-   check in-flight deadlines, and complete each request.
+   bucket's jitted program.  JAX dispatch is async, so this thread hands
+   the in-flight computation straight to the drain queue.
+3. **Drain** (host): ``block_until_ready``, copy the live slots' results
+   back as numpy (the empty slots' outputs never leave the device), check
+   in-flight deadlines, and complete each request.
 
-Every batch is padded to the bucket's ``max_batch`` so each bucket
-compiles exactly one XLA program — batch-size churn can never trigger
-recompiles on the hot path (occupancy is visible in the
-``batch_occupancy`` gauge instead).  A dispatch failure degrades the
-bucket to its jnp twin plan (once) and retries, mirroring the pre-warm
-degrade semantics; the requests still complete.
+Each bucket compiles exactly one XLA program, for ``max_batch`` slots: it
+takes a tuple of per-image inputs, stacks them into the fixed
+``(max_batch, *shape)`` batch on the device, runs the plan and returns a
+tuple of per-image results.  Batch-size churn can never trigger
+recompiles on the hot path, while the host link carries only the live
+slots; occupancy is visible in the ``batch_occupancy`` gauge, and the
+bytes moved each way in the ``h2d_bytes`` / ``d2h_bytes`` counters.  A
+dispatch failure degrades the bucket to its jnp twin plan (once) and
+retries, mirroring the pre-warm degrade semantics; the requests still
+complete.
 
-Each stage's work runs inside a :meth:`~.metrics.Metrics.span`
-(``serve.assemble``, ``serve.h2d``, ``serve.dispatch``,
-``serve.device_wait``, ``serve.copy_back``): a histogram per stage, and a
-host span in a profiler trace.  A request's ``wait`` runs from its
-admission to the start of its batch's ``assemble``: its time in the
-scheduler.
+Each stage's work runs inside a :meth:`~.metrics.Metrics.span`: a
+histogram per stage, and a host span in a profiler trace.
+``serve.assemble`` gathers each request's host planes (cast to the
+bucket's dtype; a padded-up request is zero-padded for its own slot
+alone), ``serve.h2d`` is the ``jax.device_put`` of those planes,
+``serve.dispatch`` the jitted call, ``serve.device_wait`` the
+``block_until_ready`` and ``serve.copy_back`` the ``jax.device_get`` of
+the live slots.  A request's ``wait`` runs from its admission to the
+start of its batch's ``assemble``: its time in the scheduler.
 
 ``threaded=False`` runs the identical stage functions inline through
 :meth:`PipelinedExecutor.step` — fully deterministic for the scheduler
@@ -62,6 +73,7 @@ class BucketState:
     plan: plan_lib.FFTPlan
     requested_backend: str
     fn: Optional[Callable] = None      # jitted; built at pre-warm/first use
+    zero: Optional[jax.Array] = None   # one zero input plane on the device
     degraded: bool = False
     reason: Optional[str] = None
 
@@ -81,23 +93,50 @@ def derive_max_batch(cfg: BucketConfig, plan: plan_lib.FFTPlan) -> int:
 
 
 def make_fn(state: BucketState) -> Callable:
-    """The bucket's dispatch function: one jit per bucket, compiled for
-    the fixed ``(max_batch, *shape)`` geometry."""
+    """The bucket's dispatch function: one jit per bucket, compiled for a
+    tuple of ``max_batch`` per-image inputs.  It stacks them into the
+    ``(max_batch, *shape)`` batch on the device, runs the plan, and
+    returns a tuple of ``max_batch`` per-image results."""
     plan = state.plan
-    return jax.jit(lambda x, p=plan: p(x))
+
+    def run(slots):
+        y = plan(jax.tree.map(lambda *xs: jnp.stack(xs), *slots))
+        return tuple(jax.tree.map(lambda a: a[i], y)
+                     for i in range(len(slots)))
+
+    return jax.jit(run)
 
 
-def zeros_input(cfg: BucketConfig, max_batch: int):
-    """A zero input of the bucket's compiled geometry (pre-warm and
-    compile-cache warm-up)."""
-    dt = jnp.dtype(cfg.dtype)
-    shape = (max_batch,) + cfg.shape
-    if cfg.kind == "rfft":
-        if cfg.inverse:
-            half = shape[:-1] + (cfg.shape[-1] // 2 + 1,)
-            return SplitComplex(jnp.zeros(half, dt), jnp.zeros(half, dt))
-        return jnp.zeros(shape, dt)
-    return SplitComplex(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+def _input_is_complex(cfg: BucketConfig) -> bool:
+    return cfg.kind == "c2c" or (cfg.kind == "rfft" and cfg.inverse)
+
+
+def _input_shape(cfg: BucketConfig) -> tuple:
+    """One request's input plane: the bucket's shape, or the
+    ``(..., w/2+1)`` half spectrum of an inverse rfft bucket."""
+    if cfg.kind == "rfft" and cfg.inverse:
+        return cfg.shape[:-1] + (cfg.shape[-1] // 2 + 1,)
+    return cfg.shape
+
+
+def _slot(cfg: BucketConfig, planes):
+    return SplitComplex(*planes) if _input_is_complex(cfg) else planes[0]
+
+
+def _zero(state: BucketState) -> jax.Array:
+    """The bucket's zero input plane, made on the device at first use and
+    kept on its state: it fills every empty slot and is never sent from
+    the host."""
+    if state.zero is None:
+        state.zero = jnp.zeros(_input_shape(state.cfg),
+                               jnp.dtype(state.cfg.dtype))
+    return state.zero
+
+
+def zeros_input(state: BucketState) -> tuple:
+    """``max_batch`` zero slots, the bucket's compiled signature (pre-warm
+    and compile-cache warm-up)."""
+    return (_slot(state.cfg, [_zero(state)] * 2),) * state.cfg.max_batch
 
 
 def _payload_planes(req: Request) -> List[np.ndarray]:
@@ -113,16 +152,32 @@ def _payload_planes(req: Request) -> List[np.ndarray]:
     return [arr]
 
 
-def _input_is_complex(cfg: BucketConfig) -> bool:
-    return cfg.kind == "c2c" or (cfg.kind == "rfft" and cfg.inverse)
+def _slot_planes(req: Request,
+                 cfg: BucketConfig) -> List[Optional[np.ndarray]]:
+    """A request's host planes in the bucket's dtype; None where the slot
+    takes the zero plane (the imaginary part of a real payload in a c2c
+    bucket).  A padded-up request is zero-padded here, for its own slot
+    alone: it lands in the leading corner (spectral interpolation)."""
+    shape, dt = _input_shape(cfg), np.dtype(cfg.dtype)
+    nplanes = 2 if _input_is_complex(cfg) else 1
+    out: List[Optional[np.ndarray]] = []
+    for s in _payload_planes(req)[:nplanes]:
+        s = s.astype(dt, copy=False)
+        if s.shape != shape:
+            padded = np.zeros(shape, dt)
+            padded[tuple(slice(0, d) for d in s.shape)] = s
+            s = padded
+        out.append(s)
+    return out + [None] * (nplanes - len(out))
 
 
 @dataclasses.dataclass
 class Assembled:
-    """One staged batch: device-resident input planes + its requests."""
+    """One staged batch: a tuple of ``max_batch`` device-resident slots
+    (live requests first, then zero slots) + its requests."""
     state: BucketState
     requests: List[Request]
-    x: object                          # SplitComplex or ndarray (device)
+    x: tuple
     t_staged: float = 0.0
 
 
@@ -160,27 +215,14 @@ class PipelinedExecutor:
         lbl, n = bucket.label, len(reqs)
         state = self.states[lbl]
         B = state.cfg.max_batch
-        dt = np.dtype(bucket.dtype)
-        shape = bucket.shape if not (bucket.kind == "rfft" and bucket.inverse)\
-            else bucket.shape[:-1] + (bucket.shape[-1] // 2 + 1,)
-        nplanes = 2 if _input_is_complex(bucket) else 1
         with self.metrics.span(lbl, "assemble", n) as t_picked:
-            planes = [np.zeros((B,) + shape, dt) for _ in range(nplanes)]
-            for i, req in enumerate(reqs):
-                src = _payload_planes(req)
-                if len(src) < nplanes:        # real payload into a c2c slot
-                    src = src + [np.zeros_like(src[0])]
-                for plane, s in zip(planes, src):
-                    # pad-to-bucket: a padded-up request lands in the leading
-                    # corner, zeros elsewhere (spectral interpolation)
-                    region = tuple(slice(0, d) for d in s.shape)
-                    plane[(i,) + region] = s.astype(dt, copy=False)
+            zero = _zero(state)
+            host = [_slot_planes(req, bucket) for req in reqs]
+            sent = [p for planes in host for p in planes if p is not None]
         with self.metrics.span(lbl, "h2d", n):
-            if nplanes == 2:
-                x = SplitComplex(jax.device_put(planes[0]),
-                                 jax.device_put(planes[1]))
-            else:
-                x = jax.device_put(planes[0])
+            dev = iter(jax.device_put(sent))
+            live = tuple(_slot(bucket, [zero if p is None else next(dev)
+                                        for p in planes]) for planes in host)
         now = self._clock()
         for req in reqs:
             self.metrics.observe(lbl, "wait", t_picked - req.t_submit)
@@ -188,8 +230,11 @@ class PipelinedExecutor:
         self.metrics.inc(lbl, "batches")
         self.metrics.inc(lbl, "batch_items", n)
         self.metrics.inc(lbl, "batch_pad_slots", B - n)
+        self.metrics.inc(lbl, "h2d_bytes", sum(p.nbytes for p in sent))
         self.metrics.sample(lbl, "batch_occupancy", n / B)
-        return Assembled(state=state, requests=reqs, x=x, t_staged=now)
+        empty = (_slot(bucket, [zero] * 2),) * (B - n)
+        return Assembled(state=state, requests=reqs, x=live + empty,
+                         t_staged=now)
 
     def _call_with_degrade(self, state: BucketState, x):
         """Dispatch on the bucket's plan; one failure degrades the bucket
@@ -224,13 +269,9 @@ class PipelinedExecutor:
         with self.metrics.span(lbl, "device_wait", n):
             jax.block_until_ready(y)
         with self.metrics.span(lbl, "copy_back", n):
-            if isinstance(y, SplitComplex):
-                planes = [np.asarray(y.re), np.asarray(y.im)]
-                results = [SplitComplex(planes[0][i], planes[1][i])
-                           for i in range(n)]
-            else:
-                host = np.asarray(y)
-                results = [host[i] for i in range(n)]
+            results = jax.device_get(list(y[:n]))
+        self.metrics.inc(lbl, "d2h_bytes", sum(
+            a.nbytes for a in jax.tree.leaves(results)))
         now = self._clock()
         fallback = asm.state.plan.backend != asm.state.requested_backend
         for req, val in zip(asm.requests, results):

@@ -9,13 +9,14 @@ carries), so the means add up:
 
 - ``queue``: admission → batch staged on the device.  It is ``wait``
   (admission → the staging stage starts on its batch: the scheduler's
-  share) + ``assemble`` (numpy planes allocated and payloads copied in) +
-  ``h2d`` (the ``jax.device_put`` calls as the staging thread sees them),
-  up to the spans' own microseconds;
+  share) + ``assemble`` (each request's host planes gathered in the
+  bucket's dtype) + ``h2d`` (the ``jax.device_put`` of the live
+  requests' planes as the staging thread sees it), up to the spans' own
+  microseconds;
 - ``service``: staged → results on the host; it holds ``dispatch`` (the
   fault-site check and the jitted call), ``device_wait``
-  (``block_until_ready``), ``copy_back`` (device planes to numpy, sliced
-  per request) and the hand-offs between the pipeline's threads;
+  (``block_until_ready``), ``copy_back`` (the live slots' results to
+  numpy) and the hand-offs between the pipeline's threads;
 - ``e2e``: admission → terminal.
 
 :meth:`Metrics.span` times a stage into its histogram and opens a
@@ -23,7 +24,10 @@ carries), so the means add up:
 shows as a host span beside the device ops of a profiler trace.  With no
 profiler running the annotation costs about a microsecond.  Gauges (queue
 depth at admission, batch occupancy at dispatch) keep count/sum/max
-running stats.
+running stats.  The ``h2d_bytes`` / ``d2h_bytes`` counters hold the bytes
+each batch moved across the host link, into the device and back: the
+live requests' planes only, so with ``batch_items`` and
+``batch_pad_slots`` they show the padding that stays on the device.
 
 The snapshot is a plain JSON-able dict; :func:`start_http` serves it at
 ``GET /metrics`` from a daemon thread (port 0 = ephemeral) so a load
@@ -49,7 +53,7 @@ HIST_NAMES = ("queue", "service", "e2e", "wait", "assemble", "h2d",
 COUNTERS = ("admitted", "rejected_nobucket", "rejected_backpressure",
             "padded_up", "completed", "timed_out_queued",
             "timed_out_inflight", "fallback_served", "batches",
-            "batch_items", "batch_pad_slots")
+            "batch_items", "batch_pad_slots", "h2d_bytes", "d2h_bytes")
 
 
 class LatencyHistogram:

@@ -12,11 +12,13 @@ reuses plans across requests — applied at two levels:
    twin instead of killing startup, integrating with the same resilience
    policy the guarded executor uses.
 2. **XLA compilation**: each bucket's jitted dispatch function executes
-   once on zeros of its fixed ``(max_batch, *shape)`` geometry, so no
-   client request ever pays the compile.  A compile/execute failure
-   degrades the bucket (jnp twin, recompile) rather than raising; if even
-   the twin fails, the bucket is recorded as failed in the report and the
-   runtime degrade path retries at first dispatch — startup never crashes.
+   once on ``max_batch`` zero slots — the bucket's zero plane, made on
+   the device here and kept on its state for the empty slots of every
+   later batch — so no client request ever pays the compile.  A
+   compile/execute failure degrades the bucket (jnp twin, recompile)
+   rather than raising; if even the twin fails, the bucket is recorded as
+   failed in the report and the runtime degrade path retries at first
+   dispatch — startup never crashes.
 
 :func:`compile_states` returns a :class:`PrewarmReport` with per-bucket
 compile seconds and degrade reasons — the benchmark's cold-p99 comparison
@@ -70,7 +72,7 @@ def compile_states(states: Dict[str, BucketState],
     entries = []
     for label, state in states.items():
         t0 = time.perf_counter()
-        x = zeros_input(state.cfg, state.cfg.max_batch)
+        x = zeros_input(state)
         try:
             state.fn = make_fn(state)
             jax.block_until_ready(state.fn(x))

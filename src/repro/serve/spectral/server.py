@@ -155,7 +155,11 @@ class SpectralServer:
         """Admit one request.  Returns False under backpressure (queue
         bound hit — nothing recorded, retry later); raises
         :class:`NoBucketError` when no bucket serves the shape (the
-        ``rejected_nobucket`` counter still ticks); True on admission."""
+        ``rejected_nobucket`` counter still ticks); True on admission.
+
+        The server keeps a reference to ``payload``, not a copy, and may
+        read it until the request's result arrives (the transfer to the
+        device is asynchronous): do not change the array before then."""
         if not self._accepting:
             return False
         shape = self._payload_shape(payload, kind, inverse)

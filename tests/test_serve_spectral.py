@@ -3,6 +3,7 @@ pre-warm/degrade, deadlines, drain-on-shutdown, and the load generator."""
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -304,6 +305,101 @@ def test_server_pad_up_matches_zero_padded_fft():
         assert got.shape == (64, 64)
         assert np.max(np.abs(got - ref)) / np.max(np.abs(ref)) < 1e-4
         assert srv.metrics.counter("c2c/f/64x64", "padded_up") == 1
+
+
+# Each slot-path case: (bucket, submit kwargs, payload of one request).
+SLOT_MAX_BATCH = 4
+SLOT_CASES = {
+    "c2c": (BucketConfig((64, 64), max_batch=SLOT_MAX_BATCH), {},
+            lambda rng: _c2c_payload(rng, (64, 64))),
+    "rfft": (BucketConfig((64, 64), kind="rfft", max_batch=SLOT_MAX_BATCH),
+             {"kind": "rfft"},
+             lambda rng: rng.standard_normal((64, 64)).astype(np.float32)),
+    "irfft": (BucketConfig((64, 64), kind="rfft", inverse=True,
+                           max_batch=SLOT_MAX_BATCH),
+              {"kind": "rfft", "inverse": True},
+              lambda rng: _c2c_payload(rng, (64, 33))),
+    "pad_up": (BucketConfig((64, 64), max_batch=SLOT_MAX_BATCH), {},
+               lambda rng: _c2c_payload(rng, (48, 40))),
+    "real_into_c2c": (BucketConfig((64, 64), max_batch=SLOT_MAX_BATCH), {},
+                      lambda rng: rng.standard_normal((64, 64))
+                      .astype(np.float32)),
+}
+
+
+@pytest.fixture(scope="module")
+def slot_servers():
+    """One pre-warmed server per (case, mode), shared by the live counts;
+    each comes with the bucket's plan jitted on a padded host batch, the
+    path the slot program replaced."""
+    made = {}
+
+    def get(case, threaded):
+        if (case, threaded) not in made:
+            bucket = SLOT_CASES[case][0]
+            srv = SpectralServer([bucket], threaded=threaded,
+                                 unmatched="pad_up")
+            plan = srv.states[bucket.label].plan
+            made[case, threaded] = (srv, jax.jit(lambda x, p=plan: p(x)))
+        return made[case, threaded]
+
+    yield get
+    for srv, _ in made.values():
+        srv.close()
+
+
+def _padded_batch(payloads, bucket):
+    """The bucket's ``(max_batch, *shape)`` input planes with each
+    payload zero-padded into its slot, as a host batch."""
+    shape = bucket.shape[:-1] + (bucket.shape[-1] // 2 + 1,) \
+        if bucket.inverse else bucket.shape
+    complex_in = bucket.kind == "c2c" or bucket.inverse
+    planes = [np.zeros((bucket.max_batch,) + shape, np.float32)
+              for _ in range(2 if complex_in else 1)]
+    for i, x in enumerate(payloads):
+        src = [x.re, x.im] if isinstance(x, SplitComplex) else [x]
+        for plane, s in zip(planes, src):
+            plane[(i,) + tuple(slice(0, d) for d in s.shape)] = s
+    return SplitComplex(*planes) if complex_in else planes[0]
+
+
+@pytest.mark.parametrize("threaded", [False, True],
+                         ids=["inline", "threaded"])
+@pytest.mark.parametrize("live", [1, 3, SLOT_MAX_BATCH])
+@pytest.mark.parametrize("case", list(SLOT_CASES))
+def test_slot_path_matches_plan_on_padded_batch(slot_servers, case, live,
+                                                threaded):
+    """Each request served through the per-slot program gets, bit for
+    bit, what the bucket's plan returns for its image inside a padded
+    full batch, with 1, some or all of the slots live."""
+    bucket, kw, make = SLOT_CASES[case]
+    srv, padded_plan = slot_servers(case, threaded)
+    rng = np.random.default_rng(100 + live)
+    payloads = [make(rng) for _ in range(live)]
+    before = srv.metrics.counter(bucket.label, "batches")
+    release = threading.Event()
+    if threaded:
+        # hold the staging thread until every request is queued, so the
+        # batch carries exactly ``live`` slots
+        pick = srv.scheduler.next_batch
+        srv.scheduler.next_batch = lambda: (release.wait(30), pick())[1]
+        time.sleep(0.05)        # any call already under way finds no work
+    for i, x in enumerate(payloads):
+        assert srv.submit((case, live, i), x, **kw)
+    release.set()
+    assert srv.drain(timeout_s=120)
+    if threaded:
+        srv.scheduler.next_batch = pick
+    assert srv.metrics.counter(bucket.label, "batches") == before + 1
+    want = padded_plan(_padded_batch(payloads, bucket))
+    for i in range(live):
+        rec = srv.result((case, live, i))
+        assert rec.status == "completed"
+        got = [np.asarray(a) for a in jax.tree.leaves(rec.value)]
+        ref = [np.asarray(a)[i] for a in jax.tree.leaves(want)]
+        assert [g.shape for g in got] == [r.shape for r in ref]
+        for g, r in zip(got, ref):
+            np.testing.assert_array_equal(g, r)
 
 
 def test_server_rejects_unmatched_and_counts_it():
