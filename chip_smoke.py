@@ -1,7 +1,7 @@
 """Chip smoke test: 2-D FFT serving on a TPU through compiled Pallas kernels.
 
     python chip_smoke.py             # one chip: SpectralServer, 4 buckets
-    python chip_smoke.py --chips 4   # pencil pfft2/prfft2 on a 4-chip mesh
+    python chip_smoke.py --chips 4   # pencil pfft2/prfft2/pfilter2, 4 chips
 
 One chip: builds the ``SpectralServer`` that ``python -m repro.launch.serve
 --workload spectral --buckets 256x256,1024x1024`` builds (c2c and rfft,
@@ -14,7 +14,8 @@ schedule, any missed error bound or any exception fails the run.
 
 Four chips: the pencil transforms ``repro.dist.pencil.pfft2`` and
 ``prfft2`` on a 4096x4096 fp32 input whose rows are sharded over a
-4-device mesh, against float64 numpy, and nothing else.
+4-device mesh, and ``pfilter2`` (prfft2 -> Helmholtz operator -> pirfft2)
+on a stack of 8 such fields, against float64 numpy, and nothing else.
 
 The last line of standard output is the JSON verdict
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``;
@@ -187,9 +188,11 @@ def registry_phase(sizes=(256, 1024), *, seed: int = 1) -> None:
 
 # -- four chips: the pencil transforms ---------------------------------------
 
-def pencil_phase(n: int = 4096, *, chips: int = 4, seed: int = 2) -> None:
+def pencil_phase(n: int = 4096, *, chips: int = 4, fields: int = 8,
+                 seed: int = 2) -> None:
     """pfft2 / prfft2 (default ``backend="jnp"``) with rows sharded over a
-    ``chips``-device mesh built from ``jax.devices()``."""
+    ``chips``-device mesh built from ``jax.devices()``, then ``pfilter2`` on
+    a stack of ``fields``."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -209,7 +212,7 @@ def pencil_phase(n: int = 4096, *, chips: int = 4, seed: int = 2) -> None:
         devs = {s.device for s in a.addressable_shards}
         shapes = {s.data.shape for s in a.addressable_shards}
         check(len(devs) == chips and len(shapes) == 1
-              and next(iter(shapes))[0] * chips == a.shape[0],
+              and next(iter(shapes))[-2] * chips == a.shape[-2],
               f"{what}: shards {sorted(map(str, devs))} of shapes {shapes} "
               f"are not {chips} row blocks on distinct devices")
         return len(devs)
@@ -241,6 +244,30 @@ def pencil_phase(n: int = 4096, *, chips: int = 4, seed: int = 2) -> None:
     log(f"prfft2 {n}x{n} fp32 on {nd} devices: rel_l2={err:.3e} "
         f"first_call_s={dt:.2f} (includes compile)")
     check(err <= FP32_BOUND, f"prfft2: relative L2 {err:.3e} > {FP32_BOUND}")
+
+    # pfilter2, the pencil cell's step: a stack of fields through prfft2 ->
+    # Helmholtz operator (DC and Nyquist columns differ) -> pirfft2
+    nu_dt = 1e-5
+    ky = np.fft.fftfreq(n, 1.0 / n)[:, None]
+    kx = np.fft.rfftfreq(n, 1.0 / n)[None, :]
+    g = (1.0 / (1.0 + nu_dt * (ky ** 2 + kx ** 2))).astype(np.float32)
+    xb = rng.standard_normal((fields, n, n)).astype(np.float32)
+    xs = jax.device_put(jnp.asarray(xb),
+                        NamedSharding(mesh, P(None, "data", None)))
+    op = pencil.shard_half_operator(g, mesh)
+    t0 = time.perf_counter()
+    out = jax.jit(lambda a, o: pencil.pfilter2(a, o, mesh))(xs, op)
+    jax.block_until_ready(out)
+    dt = time.perf_counter() - t0
+    nd = devices_of(out, "pfilter2 output")
+    x64 = xb.astype(np.float64)
+    ref = np.fft.irfft2(np.fft.rfft2(x64) * g.astype(np.float64), s=(n, n))
+    err = float(np.linalg.norm(np.asarray(out, np.float64) - ref)
+                / np.linalg.norm(ref))
+    log(f"pfilter2 {fields}x{n}x{n} fp32 on {nd} devices: rel_l2={err:.3e} "
+        f"first_call_s={dt:.2f} (includes compile)")
+    check(err <= FP32_BOUND,
+          f"pfilter2: relative L2 {err:.3e} > {FP32_BOUND}")
 
 
 def main(argv=None) -> int:

@@ -4,7 +4,7 @@ scale.
 The single-chip 2-D FFT in the paper is *local row FFTs -> global transpose
 -> local column FFTs*; scaled across devices that global transpose becomes
 an ``all_to_all`` over pencils (the slab/pencil decomposition every
-distributed FFT library is built on).  Four transforms live here:
+distributed FFT library is built on).  What lives here:
 
 - :func:`pfft2`               2-D FFT, rows sharded over one mesh axis.  One
                               all_to_all replaces the HBM transpose; the
@@ -36,6 +36,11 @@ distributed FFT library is built on).  Four transforms live here:
                               pencils cross the wire: **half** of
                               :func:`pfft2`'s exchange bytes, the ROADMAP's
                               "halve the all_to_all bytes" follow-on.
+                              Leading axes are a batch of fields.
+- :func:`pfilter2`            A spectral operator between the two:
+                              ``irfft2(rfft2(x) * g)`` as one program, the
+                              operator applied in the packed layout
+                              (:func:`shard_half_operator` places it).
 
 Every all_to_all optionally passes through the compressed wire formats of
 :mod:`repro.dist.compression` (``compress="bf16"``/``"int8"``), and records
@@ -55,17 +60,22 @@ and any autotune decisions from the single-chip path are reused per local
 shape; ``backend="pallas"`` switches the local passes onto the Pallas
 kernels.  Everything operates on :class:`~repro.core.complexmath.SplitComplex`
 (separate re/im planes — no complex dtype anywhere, mirroring the Tensix
-constraint).
+constraint).  The local passes and the exchanges of the real-input
+transforms run under ``jax.named_scope`` names (``pencil.row_rfft``,
+``pencil.a2a``, ``pencil.col_fft``, ``pencil.operator``, ``pencil.col_ifft``,
+``pencil.row_irfft``), which the compiled program's op metadata carries.
 """
 from __future__ import annotations
 
 import collections
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.complexmath import SplitComplex
+from repro.core.complexmath import SplitComplex, mul
 from repro.core import fft1d
 from repro.core import plan as plan_lib
 
@@ -224,22 +234,23 @@ def _a2a(x: SplitComplex, axis_name: str, split_axis: int, concat_axis: int,
     the exchange's relative global-energy delta is appended as a traced
     replicated scalar for the transform body to return."""
     _log_wire(tag, method, wire_bytes((x.re, x.im), method))
-    if collect is not None:
-        e0 = jax.lax.psum(_payload_energy(x), axis_name)
-    if method == "none":
-        y = SplitComplex(
-            all_to_all(x.re, axis_name, split_axis, concat_axis),
-            all_to_all(x.im, axis_name, split_axis, concat_axis))
-    else:
-        y = SplitComplex(
-            all_to_all_compressed(x.re, axis_name, split_axis, concat_axis,
-                                  method),
-            all_to_all_compressed(x.im, axis_name, split_axis, concat_axis,
-                                  method))
-    y = _wire_fault(y, axis_name, tag)
-    if collect is not None:
-        e1 = jax.lax.psum(_payload_energy(y), axis_name)
-        collect.append(jnp.abs(e1 - e0) / (e0 + 1e-30))
+    with jax.named_scope("pencil.a2a"):
+        if collect is not None:
+            e0 = jax.lax.psum(_payload_energy(x), axis_name)
+        if method == "none":
+            y = SplitComplex(
+                all_to_all(x.re, axis_name, split_axis, concat_axis),
+                all_to_all(x.im, axis_name, split_axis, concat_axis))
+        else:
+            y = SplitComplex(
+                all_to_all_compressed(x.re, axis_name, split_axis,
+                                      concat_axis, method),
+                all_to_all_compressed(x.im, axis_name, split_axis,
+                                      concat_axis, method))
+        y = _wire_fault(y, axis_name, tag)
+        if collect is not None:
+            e1 = jax.lax.psum(_payload_energy(y), axis_name)
+            collect.append(jnp.abs(e1 - e0) / (e0 + 1e-30))
     return y
 
 
@@ -410,11 +421,73 @@ def _fit_last(x: SplitComplex, n: int) -> SplitComplex:
     return SplitComplex(jnp.pad(x.re, pad), jnp.pad(x.im, pad))
 
 
+def _set_row0_on_owner(plane, row, axis: str):
+    """``plane`` (..., rows, cols) with its local row 0 replaced by ``row``
+    on the device that owns global row 0 (``axis_index == 0``)."""
+    own0 = jax.lax.axis_index(axis) == 0
+    return plane.at[..., 0, :].set(jnp.where(own0, row, plane[..., 0, :]))
+
+
+def _rows_spec(ndim: int, axis: str):
+    """Spec of a (..., rows, cols) array whose rows (axis -2) are sharded
+    over ``axis``; leading batch axes stay whole on every device."""
+    return P(*([None] * (ndim - 2)), axis, None)
+
+
+def _prfft2_local(xr, axis: str, *, compress: str, backend: str,
+                  collect=None) -> SplitComplex:
+    """:func:`prfft2`'s per-device body up to its column FFTs: real
+    (..., H/p, W) -> packed (..., H, W/(2p)), columns transformed."""
+    w = xr.shape[-1]
+    with jax.named_scope("pencil.row_rfft"):
+        pl = plan_lib.get_plan((w,), dtype=xr.dtype, kind="rfft",
+                               backend=backend)
+        y = _pack_rows(pl(xr))                   # (..., H/p, W/2) packed
+    z = _a2a(y, axis, y.re.ndim - 1, y.re.ndim - 2, method=compress,
+             tag="prfft2/a2a", collect=collect)  # (..., H, W/(2p))
+    with jax.named_scope("pencil.col_fft"):
+        return _fft_axis(z, -2, inverse=False, backend=backend)
+
+
+def _pirfft2_local(zin: SplitComplex, axis: str, h_out: int, w_out: int, *,
+                   compress: str, backend: str, collect=None):
+    """:func:`pirfft2`'s per-device body: packed transposed
+    (..., W/(2p), h_in) -> real (..., h_out/p, w_out)."""
+    h_in = zin.shape[-1]
+    with jax.named_scope("pencil.col_ifft"):
+        z = _fit_last(zin, h_out)                # numpy ifft n= fit
+        z = _fft_last(z, inverse=True, backend=backend)
+        if h_out != h_in:
+            # the H fit breaks the packed column's Hermitian symmetry (a
+            # cropped/padded DC column no longer inverse-transforms to a
+            # real signal), so the packed column is untangled at full
+            # height, fitted and transformed as two real columns, and
+            # spliced back on the device that owns global column 0
+            dc, ny = _split_packed_col(
+                SplitComplex(zin.re[..., 0, :], zin.im[..., 0, :]))
+            a = _fft_last(_fit_last(dc, h_out), inverse=True,
+                          backend=backend)
+            b = _fft_last(_fit_last(ny, h_out), inverse=True,
+                          backend=backend)
+            z = SplitComplex(_set_row0_on_owner(z.re, a.re, axis),
+                             _set_row0_on_owner(z.im, b.re, axis))
+    z = _a2a(z, axis, z.re.ndim - 1, z.re.ndim - 2, method=compress,
+             tag="pirfft2/a2a", collect=collect)  # (..., W/2, h_out/p)
+    with jax.named_scope("pencil.row_irfft"):
+        z = _swap_last2(z)                       # (..., h_out/p, W/2) packed
+        half = fft1d._fit_half_spectrum(_unpack_rows(z), w_out)
+        pl = plan_lib.get_plan((w_out,), dtype=z.dtype, kind="rfft",
+                               inverse=True, backend=backend)
+        return pl(half)                          # real (..., h_out/p, w_out)
+
+
 def prfft2(x: jnp.ndarray, mesh, axis: str = "data", *,
            transposed_output: bool = True, compress: str = "none",
            backend: str = "jnp", verify: bool = False) -> SplitComplex:
-    """Real-input 2-D pencil FFT of a real (H, W) array row-sharded over
-    ``axis``: the distributed :func:`repro.core.fft2d.rfft2`.
+    """Real-input 2-D pencil FFT of real (..., H, W) fields whose rows
+    (axis -2) are sharded over ``axis``: the distributed
+    :func:`repro.core.fft2d.rfft2`.  Leading axes are a batch: one
+    all_to_all per plane carries all of it.
 
     Schedule per device (p = mesh size along ``axis``):
 
@@ -426,11 +499,11 @@ def prfft2(x: jnp.ndarray, mesh, axis: str = "data", *,
        exchange bytes — to (H, W/(2p));
     4. local column FFTs on the full-height packed pencils.
 
-    Output (default) is the packed transposed half spectrum (W/2, H)
-    sharded over ``axis``; :func:`unpack_half_spectrum` expands it to the
+    Output (default) is the packed transposed half spectrum (..., W/2, H)
+    sharded over its rows; :func:`unpack_half_spectrum` expands it to the
     standard (W/2+1, H) = ``rfft2(x).T``.  ``transposed_output=False``
     spends a second (still packed, still halved) all_to_all to return the
-    natural row-sharded (H/p, W/2) layout instead.  ``verify=True``
+    natural row-sharded (..., H/p, W/2) layout instead.  ``verify=True``
     checksums the exchanges as in :func:`pfft2`.
     """
     h, w = x.shape[-2], x.shape[-1]
@@ -440,25 +513,21 @@ def prfft2(x: jnp.ndarray, mesh, axis: str = "data", *,
 
     def run(collect=None):
         def body(xr):
-            pl = plan_lib.get_plan((w,), dtype=xr.dtype, kind="rfft",
-                                   backend=backend)
-            y = _pack_rows(pl(xr))               # (H/p, W/2) packed
-            z = _a2a(y, axis, 1, 0, method=compress,
-                     tag="prfft2/a2a", collect=collect)  # (H, W/(2p))
-            z = _fft_axis(z, 0, inverse=False, backend=backend)
+            z = _prfft2_local(xr, axis, compress=compress, backend=backend,
+                              collect=collect)
             if transposed_output:
-                out = _swap_last2(z)             # (W/(2p), H)
+                out = _swap_last2(z)             # (..., W/(2p), H)
             else:
-                out = _a2a(z, axis, 0, 1, method=compress,
-                           tag="prfft2/a2a_out",
-                           collect=collect)      # (H/p, W/2) natural
+                out = _a2a(z, axis, z.re.ndim - 2, z.re.ndim - 1,
+                           method=compress, tag="prfft2/a2a_out",
+                           collect=collect)      # (..., H/p, W/2) natural
             if collect is None:
                 return out
             return out, _max_delta(collect)
 
-        out_spec = P(axis, None)
-        outs = SplitComplex(out_spec, out_spec)
-        fn = shard_map_unchecked(body, mesh=mesh, in_specs=(P(axis, None),),
+        spec = _rows_spec(x.ndim, axis)
+        outs = SplitComplex(spec, spec)
+        fn = shard_map_unchecked(body, mesh=mesh, in_specs=(spec,),
                                  out_specs=outs if collect is None
                                  else (outs, P()))
         return fn(x)
@@ -472,8 +541,9 @@ def prfft2(x: jnp.ndarray, mesh, axis: str = "data", *,
 def pirfft2(xf: SplitComplex, mesh, axis: str = "data", *, s=None,
             compress: str = "none", backend: str = "jnp",
             verify: bool = False) -> jnp.ndarray:
-    """Inverse of :func:`prfft2`: packed transposed half spectrum (W/2, H)
-    sharded over ``axis`` -> real (H, W) row-sharded.
+    """Inverse of :func:`prfft2`: packed transposed half spectra
+    (..., W/2, H) sharded over their rows (axis -2) -> real (..., H, W)
+    row-sharded.
 
     ``s=(h, w)`` follows ``numpy.fft.irfft2`` truncate/pad semantics.  Both
     fits are *local*: the H fit happens on the full-height pencils before
@@ -481,50 +551,25 @@ def pirfft2(xf: SplitComplex, mesh, axis: str = "data", *, s=None,
     after the exchange — so explicit shapes never cost extra wire.
     """
     hw, h_in = xf.shape[-2], xf.shape[-1]
-    w_full = 2 * hw
     p = mesh.shape[axis]
-    h_out, w_out = (int(s[0]), int(s[1])) if s is not None else (h_in, w_full)
+    h_out, w_out = (int(s[0]), int(s[1])) if s is not None else (h_in, 2 * hw)
     assert w_out % 2 == 0 and w_out >= 2, \
         f"pirfft2 needs an even output width, got s={s}"
     assert hw % p == 0 and h_out % p == 0, (xf.shape, s, p)
 
     def run(collect=None):
         def body(re, im):
-            zin = SplitComplex(re, im)               # (W/(2p), h_in)
-            z = _fit_last(zin, h_out)                # numpy ifft n= fit
-            z = _fft_last(z, inverse=True, backend=backend)  # (W/(2p), h_out)
-            if h_out != h_in:
-                # the H fit breaks the packed column's Hermitian symmetry (a
-                # cropped/padded DC column no longer inverse-transforms to a
-                # real signal), so the packed column is untangled at full
-                # height, fitted and transformed as two real columns, and
-                # spliced back on the device that owns global column 0
-                dc, ny = _split_packed_col(
-                    SplitComplex(zin.re[0], zin.im[0]))
-                a = _fft_last(_fit_last(dc, h_out), inverse=True,
-                              backend=backend)
-                b = _fft_last(_fit_last(ny, h_out), inverse=True,
-                              backend=backend)
-                own0 = jax.lax.axis_index(axis) == 0
-                z = SplitComplex(
-                    z.re.at[0].set(jnp.where(own0, a.re, z.re[0])),
-                    z.im.at[0].set(jnp.where(own0, b.re, z.im[0])))
-            z = _a2a(z, axis, 1, 0, method=compress,
-                     tag="pirfft2/a2a", collect=collect)  # (W/2, h_out/p)
-            z = _swap_last2(z)                       # (h_out/p, W/2) packed
-            half = fft1d._fit_half_spectrum(_unpack_rows(z), w_out)
-            pl = plan_lib.get_plan((w_out,), dtype=z.dtype, kind="rfft",
-                                   inverse=True, backend=backend)
-            out = pl(half)                           # real (h_out/p, w_out)
+            out = _pirfft2_local(SplitComplex(re, im), axis, h_out, w_out,
+                                 compress=compress, backend=backend,
+                                 collect=collect)
             if collect is None:
                 return out
             return out, _max_delta(collect)
 
-        out_spec = P(axis, None)
-        fn = shard_map_unchecked(body, mesh=mesh,
-                                 in_specs=(P(axis, None), P(axis, None)),
-                                 out_specs=out_spec if collect is None
-                                 else (out_spec, P()))
+        spec = _rows_spec(xf.re.ndim, axis)
+        fn = shard_map_unchecked(body, mesh=mesh, in_specs=(spec, spec),
+                                 out_specs=spec if collect is None
+                                 else (spec, P()))
         return fn(xf.re, xf.im)
 
     if not verify:
@@ -533,21 +578,118 @@ def pirfft2(xf: SplitComplex, mesh, axis: str = "data", *, s=None,
                          method=compress)
 
 
+# ---------------------------------------------------------------------------
+# Spectral operators in the packed layout
+# ---------------------------------------------------------------------------
+# A real filter's operator g lives on the natural half spectrum (H, W/2+1).
+# Rows 1..W/2-1 of prfft2's packed transposed output are plain columns kx of
+# the spectrum and take g[:, kx] elementwise.  Row 0 is DC + i*Nyquist, and
+# an operator whose DC and Nyquist columns differ (a Helmholtz solve, a
+# derivative) cannot multiply it as it stands: the device that owns global
+# row 0 untangles it, applies each column its own operator and repacks.  That
+# needs the DC and Nyquist columns of g to be Hermitian in ky, as a real
+# filter's are (an ``i*ky`` derivative with its ky = -H/2 row zeroed).
+
+
+class HalfOperator(NamedTuple):
+    """A spectral operator in :func:`prfft2`'s packed transposed layout, as
+    :func:`shard_half_operator` places it.  ``im`` planes are None for a
+    real operator."""
+
+    rows: SplitComplex   # (W/2, H) row-sharded: row kx holds g[:, kx]
+                         # (row 0 the DC column)
+    nyq: SplitComplex    # (H,) on every device: the Nyquist column
+
+
+def shard_half_operator(g, mesh, axis: str = "data") -> HalfOperator:
+    """Place an operator ``g`` given on the natural half spectrum
+    (H, W/2+1) -- real, or complex with a real filter's symmetry -- in
+    float32 on ``mesh`` for :func:`pfilter2`.  Runs on the host, once."""
+    g = np.asarray(g)
+    if g.ndim != 2 or g.shape[1] < 2:
+        raise ValueError(f"g must be an (H, W/2+1) half spectrum, got "
+                         f"{g.shape}")
+    hw = g.shape[1] - 1
+    if hw % mesh.shape[axis]:
+        raise ValueError(f"W/2 = {hw} rows do not divide over "
+                         f"{mesh.shape[axis]} devices")
+
+    def place(a, spec):
+        put = lambda b: jax.device_put(np.ascontiguousarray(b, np.float32),
+                                       NamedSharding(mesh, spec))
+        if np.iscomplexobj(a):
+            return SplitComplex(put(a.real), put(a.imag))
+        return SplitComplex(put(a), None)
+
+    return HalfOperator(place(g[:, :hw].T, P(axis, None)),
+                        place(g[:, hw], P()))
+
+
+def _times(z: SplitComplex, g: SplitComplex) -> SplitComplex:
+    """z * g, broadcast over z's leading axes; ``g.im`` None is real."""
+    if g.im is None:
+        return SplitComplex(z.re * g.re, z.im * g.re)
+    return mul(z, g)
+
+
+def _apply_half_operator(z: SplitComplex, op: HalfOperator,
+                         axis: str) -> SplitComplex:
+    """Multiply the local block (..., W/(2p), H) of a packed transposed
+    half spectrum by ``op``'s local rows; on the owner of global row 0,
+    that row is untangled into A (DC) and B (Nyquist), A takes the DC
+    column, B the Nyquist column, and A' + i*B' is packed back."""
+    out = _times(z, op.rows)
+    a, b = _split_packed_col(SplitComplex(z.re[..., 0, :], z.im[..., 0, :]))
+    dc = SplitComplex(op.rows.re[0],
+                      None if op.rows.im is None else op.rows.im[0])
+    a, b = _times(a, dc), _times(b, op.nyq)
+    return SplitComplex(_set_row0_on_owner(out.re, a.re - b.im, axis),
+                        _set_row0_on_owner(out.im, a.im + b.re, axis))
+
+
+def pfilter2(x: jnp.ndarray, op: HalfOperator, mesh,
+             axis: str = "data") -> jnp.ndarray:
+    """``irfft2(rfft2(x) * g)`` of real (..., H, W) fields whose rows are
+    sharded over ``axis``, as one shard_map program: the body of
+    :func:`prfft2`, the operator ``op`` (from :func:`shard_half_operator`)
+    on the packed transposed half spectrum, then the body of
+    :func:`pirfft2`.  Two uncompressed all_to_alls, no gather; local passes
+    on the plan registry's jnp plans; the output is row-sharded like
+    ``x``."""
+    h, w = x.shape[-2], x.shape[-1]
+    p = mesh.shape[axis]
+    assert w % 2 == 0 and h % p == 0 and (w // 2) % p == 0, (x.shape, p)
+    assert op.rows.shape == (w // 2, h), (op.rows.shape, x.shape)
+
+    def body(xr, g):
+        z = _swap_last2(_prfft2_local(xr, axis, compress="none",
+                                      backend="jnp"))  # (..., W/(2p), H)
+        with jax.named_scope("pencil.operator"):
+            z = _apply_half_operator(z, g, axis)
+        return _pirfft2_local(z, axis, h, w, compress="none", backend="jnp")
+
+    spec = _rows_spec(x.ndim, axis)
+    fn = shard_map_unchecked(
+        body, mesh=mesh, in_specs=(spec, HalfOperator(P(axis, None), P())),
+        out_specs=spec)
+    return fn(x, op)
+
+
 def exchange_bytes(h: int, w: int, devices: int, *, real: bool = False,
                    method: str = "none", dtype=jnp.float32,
-                   transposed_output: bool = True) -> int:
+                   transposed_output: bool = True, batch: int = 1) -> int:
     """Per-device all_to_all *payload* bytes of one :func:`pfft2` /
-    :func:`prfft2` call — exactly what the wire log records.
+    :func:`prfft2` call on ``batch`` fields — exactly what the wire log
+    records.
     :func:`repro.tt.trace.trace_dist` prices the (devices-1)/devices
     fraction of this that actually leaves the chip.  ``real=True`` halves
     the column count (the packed half spectrum); the per-element wire
     width derives from :func:`repro.dist.compression.wire_bytes` on a
     probe leaf so the two pricings can never drift."""
-    import numpy as np
     cols = w // 2 if real else w
     legs = 1 if transposed_output else 2
     per_elem = wire_bytes(np.zeros((1,), jnp.dtype(dtype)), method)
-    return legs * 2 * (h // devices) * cols * per_elem
+    return legs * 2 * batch * (h // devices) * cols * per_elem
 
 
 # ---------------------------------------------------------------------------
