@@ -7,9 +7,9 @@ Two execution paths behind the plan registry's ``backend`` switch:
   transpose between the two 1-D passes; XLA lowers it to an in-HBM relayout.
 - ``backend="pallas"`` — the GEMM-formulated fused kernel
   (:mod:`repro.kernels.fft2d_gemm`, ``algo="fused"``): both 1-D passes run
-  as dense DFT matmuls inside one kernel, the column pass as left-side
-  contractions, so the global transpose never materialises anywhere — not
-  in HBM, not even in VMEM.  ``algo="fused_stockham"`` keeps the previous
+  as DFT matmuls inside one kernel — a radix-r VPU combine and 128-point
+  products for fp32 axes of 256–1024, dense passes otherwise — so the
+  global transpose never reaches HBM.  ``algo="fused_stockham"`` keeps the previous
   Stockham-stage fused kernel (:mod:`repro.kernels.fft2d_fused`) as the
   explicit-algo oracle, and ``algo="row_col"`` the transpose-based
   two-kernel pipeline as the measured baseline.

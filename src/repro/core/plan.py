@@ -118,6 +118,19 @@ class FFTPlan:
     def ndim(self) -> int:
         return len(self.shape)
 
+    @property
+    def pass_splits(self):
+        """``(h, w)`` splits of a 2-D pallas c2c plan's complex kernel: per
+        axis ``(r, 128)`` where its pass runs as a radix-``r`` combine and
+        128-point products, ``None`` where it runs dense
+        (:func:`repro.kernels.fft2d_gemm.axis_splits`).  ``None`` for
+        plans that run no such kernel."""
+        if (self.ndim, self.kind, self.backend, self.algo) != (
+                2, "c2c", "pallas", "fused"):
+            return None
+        from repro.kernels.fft2d_gemm import axis_splits
+        return axis_splits(*self.shape, self.dtype, self.variant)
+
     # -- construction --------------------------------------------------------
 
     @staticmethod

@@ -8,8 +8,10 @@ factors plus a pointwise inter-factor twiddle, fed by host-built float64
 tables; ``n1 == 1`` (below the leaf size) means a single dense DFT.  The
 factor split reshapes the transformed axis inside the kernel, which Mosaic
 refuses when that axis is the lane axis (``unsupported shape cast``), so
-the 2-D kernels on the TPU main path (:mod:`repro.kernels.fft2d_gemm`,
-:mod:`repro.kernels.rfft2d_fused`) run dense passes instead.
+the 2-D kernels on the TPU main path do without it: the real kernel
+(:mod:`repro.kernels.rfft2d_fused`) runs dense passes, the complex one
+(:mod:`repro.kernels.fft2d_gemm`) splits each pass over 128-lane chunks
+with no reshape.
 """
 from __future__ import annotations
 

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.complexmath import SplitComplex
 from repro.kernels import ops
+from repro.kernels.fft2d_gemm import axis_splits
 
 BATCH = 2          # > block_batch, so the grid pipelines (double buffers)
 
@@ -61,9 +62,18 @@ def _planes(shape, dtype, sharding):
     (1024, True, jnp.float32, "plain"),
     (1024, False, jnp.bfloat16, "compensated"),
     (1024, True, jnp.bfloat16, "compensated"),
+    (512, False, jnp.float32, "plain"),
+    (512, True, jnp.float32, "plain"),
+    pytest.param((256, 1024), False, jnp.float32, "plain",
+                 id="256x1024-False-float32-plain"),
 ])
 def test_fft2d_gemm_compiles(one_chip, n, inverse, dtype, variant):
-    x = _planes((BATCH, n, n), dtype, one_chip)
+    """``n`` is a square side or an (h, w) rectangle.  Plain fp32 planes
+    compile the split body (radix-r combine + 128-point products), the
+    compensated variant the dense one."""
+    h, w = (n, n) if isinstance(n, int) else n
+    assert any(axis_splits(h, w, dtype, variant)) == (variant == "plain")
+    x = _planes((BATCH, h, w), dtype, one_chip)
     txt = _compiled_text(
         lambda x: ops.fft2d_gemm(x, inverse=inverse, variant=variant,
                                  interpret=False), x)
